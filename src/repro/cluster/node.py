@@ -4,7 +4,7 @@
 ``core.cluster.EdgeNode`` (both satisfy ``core.protocols.SchedulableNode``).
 It owns
 
-  * a smoke-config ``ServeEngine`` (heterogeneous architecture per node),
+  * a ``ServeEngine`` (heterogeneous architecture per node),
   * a private domain-partitioned corpus behind a ``VectorIndex``
     backend (exact ``flat`` scan or ``ivf`` ANN probe),
   * optionally a ``SemanticQueryCache`` (repeat/near-duplicate queries
@@ -137,6 +137,7 @@ class LiveEdgeNode:
         self.stats = LiveNodeStats()
         self.last_contexts: Dict[int, List[str]] = {}
         self.last_sources: Dict[int, List[int]] = {}
+        self.last_tokens: Dict[int, List[int]] = {}
         self._key = jax.random.PRNGKey(seed)
 
     # ------------------------------------------------------------ retrieval
@@ -281,6 +282,7 @@ class LiveEdgeNode:
         results: List[QueryResult] = []
         self.last_contexts = {}
         self.last_sources = {}
+        self.last_tokens = {}
         for q, rid, ctx, src, tid in zip(queries, rids, contexts, sources,
                                          tids):
             comp = comps[rid]
@@ -295,6 +297,7 @@ class LiveEdgeNode:
                                                             q.reference)
             self.last_contexts[q.qid] = ctx
             self.last_sources[q.qid] = src
+            self.last_tokens[q.qid] = list(comp.tokens)
             self.stats.queries += 1
             self.stats.drops += int(dropped)
             results.append(QueryResult(q.qid, self.node_id, self.arch,

@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed._compat import shard_map
 
 
 def distributed_topk(queries: jax.Array, corpus: jax.Array, k: int,
@@ -53,10 +52,10 @@ def distributed_topk(queries: jax.Array, corpus: jax.Array, k: int,
         return sg, ig
 
     other = tuple(a for a in mesh.axis_names if a != axis)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P(axis, None)),
-                   out_specs=(P(), P()),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P(axis, None)),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return fn(queries, corpus)
 
 
@@ -98,9 +97,9 @@ def flash_decode_seq_sharded(
         out = o / jnp.maximum(l, 1e-30)[..., None]
         return out.reshape(B, 1, H, hd).astype(q.dtype)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P(None, axis, None, None),
-                             P(None, axis, None, None), P()),
-                   out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P(None, axis, None, None),
+                                 P(None, axis, None, None), P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(q, k_cache, v_cache, q_position)

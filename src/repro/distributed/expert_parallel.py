@@ -19,7 +19,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.distributed._compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -88,7 +87,7 @@ def apply_moe_expert_parallel(
         return y, aux
 
     bspec = batch_axes if batch_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P(axis, None, None), P(axis, None, None),

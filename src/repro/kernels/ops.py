@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (the
-Pallas interpreter executes the kernel body op-by-op, validating the
-exact TPU program); on a real TPU backend set ``interpret=False`` (the
-default resolves automatically from the platform).
+The backend decides how a kernel runs.  On the TPU it compiles for the
+chip, and the paged decode read always takes the kernel.  On any other
+backend (the CPU tests) kernels run with ``interpret=True`` — the
+Pallas interpreter executes the kernel body op by op, validating the
+exact TPU program — and the paged decode read takes its jnp oracle.
 """
 from __future__ import annotations
 

@@ -30,10 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 names CompilerParams TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -105,7 +101,7 @@ def topk_pallas(queries: jax.Array, docs: jax.Array, k: int, *,
             pltpu.VMEM((q_block, k), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(queries, docs)
     return scores[:Nq], idx[:Nq]
@@ -182,7 +178,7 @@ def ivf_topk_pallas(queries: jax.Array, list_emb: jax.Array,
             jax.ShapeDtypeStruct((Nq, k), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(probe_ids.astype(jnp.int32), queries, list_emb, list_ids)
     return scores, idx

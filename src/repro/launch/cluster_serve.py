@@ -30,11 +30,12 @@ import numpy as np
 from repro import obs
 from repro.cluster import ClusterRuntime, LiveEdgeNode, LiveWorkload, \
     enable_federation, replay_trace
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.identifier import OnlineQueryIdentifier
 from repro.data.corpus import DOMAINS, generate_corpus
 from repro.data.partition import coverage_matrix, partition_edge_data
 from repro.data.tokenizer import Tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.retrieval.cache import SemanticQueryCache
 from repro.retrieval.encoder import TextEncoder
@@ -56,6 +57,49 @@ def _load_ckpt_params(ckpt: str, arch: str, vocab: int, max_len: int):
         return cfg, checkpoint.load(ckpt, like)
     except (KeyError, AssertionError, ValueError):
         return None
+
+
+def node_config(arch: str, *, smoke: bool, vocab: int, d_model: int = 32):
+    """A node's model config: the published one (width, depth, vocab,
+    bf16; the tokenizer's few hundred ids fall inside its vocab) or, with
+    ``smoke``, the reduced CPU-sized variant over the tokenizer's vocab."""
+    if smoke:
+        return get_smoke_config(arch, max_d_model=d_model, vocab=vocab)
+    return get_config(arch)
+
+
+def param_bytes(cfg, max_len: int) -> int:
+    """Bytes of a node's weights, from shapes alone (nothing allocated)."""
+    shapes = jax.eval_shape(
+        lambda k: Model(cfg).init_params(k, max_seq=max_len),
+        jax.random.PRNGKey(0))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+
+def device_bytes_limit():
+    """Memory of the device every node shares, where the backend reports
+    it (the TPU does; the CPU backend reports nothing)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def check_weights_fit(cfgs, max_len: int) -> None:
+    """Refuse, before any weights exist, a cluster whose nodes' weights
+    alone exceed the shared device's memory — e.g. qwen2-moe-a2.7b at
+    published width (~28 GB of experts and attention in bf16) on one
+    16 GB chip — instead of running out of memory halfway through the
+    build."""
+    limit = device_bytes_limit()
+    if limit is None:
+        return
+    need = [(cfg.name, param_bytes(cfg, max_len)) for cfg in cfgs]
+    total = sum(b for _, b in need)
+    if total > limit:
+        each = ", ".join(f"{name} {b / 1e9:.2f} GB" for name, b in need)
+        raise ValueError(
+            f"node weights need {total / 1e9:.2f} GB ({each}) but the "
+            f"device holds {limit / 1e9:.2f} GB; use fewer nodes or "
+            f"--smoke")
 
 
 def build_cluster(n_nodes: int, *, smoke: bool = True, entities: int = 8,
@@ -87,9 +131,11 @@ def build_cluster(n_nodes: int, *, smoke: bool = True, entities: int = 8,
     primaries = [[d for d in range(n_domains) if d % n_nodes == n]
                  for n in range(n_nodes)]
     node_docs = partition_edge_data(docs, n_nodes, primaries, seed=seed)
+    node_archs = [archs[n % len(archs)] for n in range(n_nodes)]
+    if not smoke:
+        check_weights_fit([get_config(a) for a in node_archs], max_len)
     nodes = []
-    for n in range(n_nodes):
-        arch = archs[n % len(archs)]
+    for n, arch in enumerate(node_archs):
         loaded = _load_ckpt_params(ckpt, arch, len(tok), max_len) \
             if ckpt else None
         if loaded is not None:
@@ -100,9 +146,8 @@ def build_cluster(n_nodes: int, *, smoke: bool = True, entities: int = 8,
             if ckpt:
                 print(f"node {n} [{arch}]: ckpt arch/shape mismatch — "
                       f"random init", flush=True)
-            cfg = get_smoke_config(arch,
-                                   max_d_model=d_model if smoke else 128,
-                                   vocab=len(tok))
+            cfg = node_config(arch, smoke=smoke, vocab=len(tok),
+                              d_model=d_model)
             params = Model(cfg).init_params(jax.random.PRNGKey(seed + n),
                                             max_seq=max_len)
         nodes.append(LiveEdgeNode(
@@ -148,7 +193,8 @@ def main():
     ap.add_argument("--no-inter-node", action="store_true",
                     help="ablation: capacity-unaware identifier sampling")
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny models + corpus (CPU CI)")
+                    help="tiny models + corpus (CPU CI); without it every "
+                         "node runs its published config (bf16)")
     ap.add_argument("--entities", type=int, default=None,
                     help="entities per domain (default 8 smoke / 24 full)")
     ap.add_argument("--batch", type=int, default=4)
@@ -222,6 +268,7 @@ def main():
     if args.arrival_rate is not None:
         args.per_slot = max(1, round(args.arrival_rate * args.slot_s))
 
+    enable_compile_cache()
     rec = obs.enable() if args.trace_out else None
     # registry pushes stay on for the whole run: the SLO monitors, the
     # /metrics endpoint, and the dashboard all read from it

@@ -7,19 +7,13 @@ calls make_production_mesh().
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType                 # jax >= 0.6
-except ImportError:                                    # jax < 0.5
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

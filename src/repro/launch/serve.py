@@ -13,6 +13,7 @@ import time
 import jax
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving import GenerationParams, RequestQueue, ServeEngine
 
@@ -33,6 +34,7 @@ def main():
                     help="also time the per-token Python loop")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     key = jax.random.PRNGKey(0)

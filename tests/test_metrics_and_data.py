@@ -1,7 +1,8 @@
 """Metrics properties (hypothesis) + data substrate."""
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data.corpus import DOMAINS, generate_corpus
 from repro.data.partition import coverage_matrix, partition_edge_data

@@ -119,6 +119,31 @@ def test_poisoned_engine_generates(small_engine, poisoned):
     assert len(outs) == 2 and all(len(o) == 4 for o in outs)
 
 
+@pytest.mark.parametrize("archs", [("olmo-1b", "xlstm-350m"),
+                                   ("hymba-1.5b", "qwen2-moe-a2.7b")])
+def test_poisoned_standing_cluster_serves(archs, poisoned):
+    """The standing paged node path (frame, refill, prefix fork, COW
+    copy, decode segments straddling slots) under TPU-faithful donation:
+    every routed request is answered and none is left behind."""
+    from repro.cluster import ClusterRuntime, LiveWorkload, replay_trace
+    from repro.launch.cluster_serve import build_cluster
+    nodes, qas, _, enc, ident, _ = build_cluster(
+        2, smoke=True, archs=archs, queue="standing", paged=True,
+        admission="sjf")
+    for node in nodes:
+        poisoned(node.engine)
+    rt = ClusterRuntime(nodes, ident)
+    rt.initialize()
+    rep = replay_trace(rt, LiveWorkload(qas, enc, seed=2), n_slots=3,
+                       slo_s=30.0, base_volume=8, trace="spike")
+    lost = sum(node.unfinished() for node in nodes)
+    rt.close()
+    assert lost == 0
+    assert sum(n.stats.queries for n in nodes) \
+        == sum(m.n_queries for m in rep.slots)
+    assert all(n.stats.refills and n.stats.prefix_hits for n in nodes)
+
+
 def test_engine_decode_has_no_recompiles(small_engine, recompile_guard):
     eng = small_engine
     eng.generate([[1, 2, 3]], max_new_tokens=3)  # warm every shape

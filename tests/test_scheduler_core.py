@@ -2,7 +2,8 @@
 manager ULD/LD/RLD semantics, PPO identifier."""
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.edge_pool import MODEL_SPECS, pool_for_family
 from repro.core.inter_node import inter_node_schedule

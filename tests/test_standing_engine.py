@@ -15,8 +15,9 @@ import time
 
 import jax
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-from _hyp import given, settings, st
 from repro.configs import get_smoke_config
 from repro.models import Model
 from repro.serving import ContinuousQueue, GenerationParams, ServeEngine
@@ -282,7 +283,7 @@ def test_depth_and_oldest_wait(key):
     q.close()
 
 
-# ------------------------------------------------------------ stress (_hyp)
+# ------------------------------------------------------- stress (hypothesis)
 
 
 def _run_interleaving(eng, ops, *, max_budget=3):
