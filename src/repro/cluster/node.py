@@ -237,17 +237,21 @@ class LiveEdgeNode:
             queue.set_shed(self.shed_fraction)
             cap = self.engine.cont_max_prompt_len(self.gen.max_new_tokens)
             rids = []
-            for q, c, tid in zip(queries, contexts, tids):
-                toks, plen = split_prompt(q.question, c, self.tok, cap=cap)
-                rids.append(queue.submit(toks, prefix_len=plen, trace=tid))
+            with tr.span("tokenize", traces=tids):
+                for q, c, tid in zip(queries, contexts, tids):
+                    toks, plen = split_prompt(q.question, c, self.tok,
+                                              cap=cap)
+                    rids.append(queue.submit(toks, prefix_len=plen,
+                                             trace=tid))
             t0 = time.perf_counter()
-            if queue.standing:
-                # stream this slot into the live session and return the
-                # moment its requests finish — other rows may straddle
-                # into the next slot mid-decode
-                queue.run(wait_for=rids)
-            else:
-                queue.run()
+            with tr.span("generate", traces=tids):
+                if queue.standing:
+                    # stream this slot into the live session and return
+                    # the moment its requests finish — other rows may
+                    # straddle into the next slot mid-decode
+                    queue.run(wait_for=rids)
+                else:
+                    queue.run()
             self.stats.generate_s += time.perf_counter() - t0
             delta = queue.stats.delta(base)
             self.stats.waves += delta.frames
@@ -269,9 +273,10 @@ class LiveEdgeNode:
                 for q, c in zip(queries, contexts))
             wave_elapsed: List[float] = []
             t0 = time.perf_counter()
-            while queue.pending():
-                queue.step()
-                wave_elapsed.append(time.perf_counter() - t0)
+            with tr.span("generate", traces=tids):
+                while queue.pending():
+                    queue.step()
+                    wave_elapsed.append(time.perf_counter() - t0)
             self.stats.generate_s += wave_elapsed[-1] if wave_elapsed else 0.0
             self.stats.waves += queue.stats.waves
             self.stats.tokens_out += queue.stats.tokens_out
@@ -283,26 +288,28 @@ class LiveEdgeNode:
         self.last_contexts = {}
         self.last_sources = {}
         self.last_tokens = {}
-        for q, rid, ctx, src, tid in zip(queries, rids, contexts, sources,
-                                         tids):
-            comp = comps[rid]
-            latency = t_retrieval + done_s[rid]
-            with tr.span("detokenize", trace=tid,
-                         tokens=len(comp.tokens)):
-                answer = self.tok.decode(comp.tokens)
-            # a shed request never ran: it is a drop by decision, not by
-            # the SLO clock
-            dropped = getattr(comp, "shed", False) or latency > slo_s
-            quality = 0.0 if dropped else composite_quality(answer,
-                                                            q.reference)
-            self.last_contexts[q.qid] = ctx
-            self.last_sources[q.qid] = src
-            self.last_tokens[q.qid] = list(comp.tokens)
-            self.stats.queries += 1
-            self.stats.drops += int(dropped)
-            results.append(QueryResult(q.qid, self.node_id, self.arch,
-                                       quality, dropped,
-                                       latency_s=latency, answer=answer))
+        with tr.span("score", traces=tids):
+            for q, rid, ctx, src, tid in zip(queries, rids, contexts,
+                                             sources, tids):
+                comp = comps[rid]
+                latency = t_retrieval + done_s[rid]
+                with tr.span("detokenize", trace=tid,
+                             tokens=len(comp.tokens)):
+                    answer = self.tok.decode(comp.tokens)
+                # a shed request never ran: it is a drop by decision, not
+                # by the SLO clock
+                dropped = getattr(comp, "shed", False) or latency > slo_s
+                quality = 0.0 if dropped else composite_quality(
+                    answer, q.reference)
+                self.last_contexts[q.qid] = ctx
+                self.last_sources[q.qid] = src
+                self.last_tokens[q.qid] = list(comp.tokens)
+                self.stats.queries += 1
+                self.stats.drops += int(dropped)
+                results.append(QueryResult(q.qid, self.node_id, self.arch,
+                                           quality, dropped,
+                                           latency_s=latency,
+                                           answer=answer))
         if obs_metrics.metrics_enabled():
             self._push_metrics(queue, delta, t_retrieval, results)
         return results

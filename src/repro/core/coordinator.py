@@ -69,12 +69,15 @@ class Coordinator:
 
     def _dispatch(self, queries: Sequence[Query], assign: np.ndarray,
                   slo_s: float) -> List[QueryResult]:
+        tr = obs_trace.get_tracer()
         results: List[QueryResult] = []
         for n, node in enumerate(self.nodes):
-            idx = np.where(assign == n)[0]
-            results += node.process_slot(
-                [queries[i] for i in idx], slo_s,
-                scheduler=self.node_schedulers.get(n))
+            part = [queries[i] for i in np.where(assign == n)[0]]
+            traces = [obs_trace.query_trace(q.qid) for q in part] \
+                if tr.enabled else None
+            with tr.span("node_slot", traces=traces, node=n):
+                results += node.process_slot(
+                    part, slo_s, scheduler=self.node_schedulers.get(n))
         return results
 
     def _feedback(self, embs: np.ndarray, assign: np.ndarray,
@@ -82,10 +85,14 @@ class Coordinator:
                   ) -> np.ndarray:
         """Realized composite quality per query (dropped -> 0) into the
         identifier's buffer; triggers a PPO update when due."""
-        by_qid = {r.qid: r for r in results}
-        scores = np.array([by_qid[q.qid].quality for q in queries])
-        self.identifier.feedback(embs, assign, scores)
-        self.identifier.maybe_update()
+        tr = obs_trace.get_tracer()
+        traces = [obs_trace.query_trace(q.qid) for q in queries] \
+            if tr.enabled else None
+        with tr.span("feedback", traces=traces):
+            by_qid = {r.qid: r for r in results}
+            scores = np.array([by_qid[q.qid].quality for q in queries])
+            self.identifier.feedback(embs, assign, scores)
+            self.identifier.maybe_update()
         return scores
 
     def _slot_pipeline(self, queries: Sequence[Query], slo_s: float):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ppo
+from repro.obs import trace as obs_trace
 
 
 class OnlineQueryIdentifier:
@@ -64,15 +65,17 @@ class OnlineQueryIdentifier:
     def maybe_update(self) -> Optional[dict]:
         if self.buffered() < self.update_threshold:
             return None
-        e = jnp.asarray(np.concatenate(self._buf_e))
-        a = jnp.asarray(np.concatenate(self._buf_a))
-        f = jnp.asarray(np.concatenate(self._buf_f))
-        self._buf_e, self._buf_a, self._buf_f = [], [], []
-        self.old_params = jax.tree.map(lambda x: x, self.params)
-        metrics = {}
-        for _ in range(self.update_epochs):   # batch reuse via CLIP (Eq. 11)
-            self.params, self.opt_state, metrics = ppo.ppo_update(
-                self.params, self.old_params, self.opt_state, e, a, f,
-                eps=self.clip_eps, beta=self.entropy_beta, lr=self.lr)
-        self.updates_done += 1
-        return {k: float(v) for k, v in metrics.items()}
+        with obs_trace.get_tracer().span("ppo_update",
+                                         queries=self.buffered()):
+            e = jnp.asarray(np.concatenate(self._buf_e))
+            a = jnp.asarray(np.concatenate(self._buf_a))
+            f = jnp.asarray(np.concatenate(self._buf_f))
+            self._buf_e, self._buf_a, self._buf_f = [], [], []
+            self.old_params = jax.tree.map(lambda x: x, self.params)
+            metrics = {}
+            for _ in range(self.update_epochs):   # batch reuse via CLIP
+                self.params, self.opt_state, metrics = ppo.ppo_update(
+                    self.params, self.old_params, self.opt_state, e, a, f,
+                    eps=self.clip_eps, beta=self.entropy_beta, lr=self.lr)
+            self.updates_done += 1
+            return {k: float(v) for k, v in metrics.items()}

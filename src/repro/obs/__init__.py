@@ -23,19 +23,18 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                enable_metrics, escape_label, metric_key,
                                metrics_enabled, percentile, registry,
                                unescape_label)
-from repro.obs.recorder import (FlightRecorder, start_device_profile,
-                                stop_device_profile)
+from repro.obs.recorder import FlightRecorder
 from repro.obs.slo import (DEFAULT_WINDOWS, FIRING, OK, Objective,
                            SLOMonitor, node_objectives)
 from repro.obs.timeseries import TimeSeriesStore
-from repro.obs.trace import NULL_SPAN, Tracer, get_tracer, query_trace
+from repro.obs.trace import (NULL_SPAN, Tracer, get_tracer, query_trace,
+                             watch_compiles)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "percentile",
     "registry", "metric_key", "escape_label", "unescape_label",
-    "enable_metrics", "metrics_enabled", "FlightRecorder",
-    "start_device_profile", "stop_device_profile", "NULL_SPAN", "Tracer",
-    "get_tracer", "query_trace", "enable", "disable", "enabled",
+    "enable_metrics", "metrics_enabled", "FlightRecorder", "NULL_SPAN",
+    "Tracer", "get_tracer", "query_trace", "enable", "disable", "enabled",
     "TimeSeriesStore", "Objective", "SLOMonitor", "node_objectives",
     "DEFAULT_WINDOWS", "OK", "FIRING", "to_prometheus", "parse_prometheus",
     "parse_key", "TelemetryServer", "render_dashboard",
@@ -43,7 +42,9 @@ __all__ = [
 
 
 def enable(recorder=None, capacity=131072):
-    """Turn tracing on. Returns the recorder events will land in."""
+    """Turn tracing on (backend compiles become ``compile`` spans).
+    Returns the recorder events will land in."""
+    watch_compiles()
     rec = recorder if recorder is not None else FlightRecorder(capacity)
     tr = get_tracer()
     tr.recorder = rec

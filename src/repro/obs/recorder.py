@@ -1,14 +1,12 @@
 """Flight recorder: bounded ring buffer of span/metric events + JSONL
-export, and guarded `jax.profiler` start/stop so device traces can be
-aligned with host spans (`ServeEngine(profile=...)`).
+export.
 """
 from __future__ import annotations
 
 import json
 import os
-import warnings
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 SCHEMA_VERSION = 1
 
@@ -59,51 +57,3 @@ class FlightRecorder:
             for ev in self._buf:
                 f.write(json.dumps(ev) + "\n")
         return path
-
-
-# ------------------------------------------------------ device profiler
-
-_PROFILING = False
-_PROFILER_WARNED = False
-
-
-def _warn_profiler_once(op: str, exc: Exception):
-    """The profiler being unavailable (or a trace already running out of
-    band) must not kill serving, but it must not be invisible either:
-    warn the first time, stay quiet after."""
-    global _PROFILER_WARNED
-    if _PROFILER_WARNED:
-        return
-    _PROFILER_WARNED = True
-    warnings.warn(f"jax.profiler {op} failed ({type(exc).__name__}: {exc}); "
-                  "device profiles disabled for this process", RuntimeWarning)
-
-
-def start_device_profile(logdir: str) -> bool:
-    """Begin a jax.profiler trace into `logdir` (no-op if one is live
-    or the profiler is unavailable in this jax build)."""
-    global _PROFILING
-    if _PROFILING:
-        return False
-    try:
-        import jax
-        jax.profiler.start_trace(logdir)
-    except Exception as e:
-        _warn_profiler_once("start_trace", e)
-        return False
-    _PROFILING = True
-    return True
-
-
-def stop_device_profile() -> bool:
-    global _PROFILING
-    if not _PROFILING:
-        return False
-    _PROFILING = False
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception as e:
-        _warn_profiler_once("stop_trace", e)
-        return False
-    return True

@@ -16,12 +16,20 @@ Three shapes cover every call site in the serving hierarchy:
   endpoints were observed without a context manager (queue wait,
   admission-to-completion decode latency).
 
+While tracing is on, every live span context also enters one
+``jax.profiler.TraceAnnotation`` named ``obs.<span name>`` (a batched
+span enters one for all its traces), so a profiler trace names each
+host interval, and each device idle gap under it, by the program's own
+span.  Retroactive ``emit`` spans have no annotation.  ``watch_compiles``
+adds a retroactive ``compile`` span for each backend compile.
+
 Disabled mode is the default and is *free*: ``span()`` returns a
-shared null context manager without reading the clock (see the no-op
-test in tests/test_obs.py, which monkeypatches this module's
-``perf_counter``), and ``emit``/``event`` return immediately.
-Instrumentation must never enter jitted code — spans time host-side
-orchestration only (docs/ARCHITECTURE.md, invariants).
+shared null context manager without reading the clock or making an
+annotation (see the no-op test in tests/test_obs.py, which
+monkeypatches this module's ``perf_counter``), and ``emit``/``event``
+return immediately.  Instrumentation must never enter jitted code —
+spans time host-side orchestration only (docs/ARCHITECTURE.md,
+invariants).
 """
 from __future__ import annotations
 
@@ -69,12 +77,14 @@ class _Span:
 
 
 class _SpanCtx:
-    """Live context manager over one or more per-trace spans."""
-    __slots__ = ("_tracer", "_spans")
+    """Live context manager over one or more per-trace spans, and the
+    one profiler annotation that mirrors them (already entered)."""
+    __slots__ = ("_tracer", "_spans", "_ann")
 
-    def __init__(self, tracer, spans):
+    def __init__(self, tracer, spans, ann):
         self._tracer = tracer
         self._spans = spans
+        self._ann = ann
 
     def __enter__(self):
         return self
@@ -86,10 +96,21 @@ class _SpanCtx:
 
     def __exit__(self, *exc):
         t1 = perf_counter()
+        self._ann.__exit__(None, None, None)
         for s in self._spans:
             s.t1 = t1
             self._tracer._close(s)
         return False
+
+
+def _annotation(name: str):
+    """The profiler annotation mirroring a live span, entered.  Its start
+    and the span's ``t0`` are read back to back, which is what lets a
+    reader put span times on the profiler's clock."""
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation("obs." + name)
+    ann.__enter__()
+    return ann
 
 
 class Tracer:
@@ -109,6 +130,7 @@ class Tracer:
         one interval, one event per trace id."""
         if not self.enabled:
             return NULL_SPAN
+        ann = _annotation(name)
         t0 = perf_counter()
         tids = list(traces) if traces is not None else [trace]
         if not tids:
@@ -122,7 +144,7 @@ class Tracer:
                       dict(attrs) if attrs else None)
             stack.append(s.sid)
             spans.append(s)
-        return _SpanCtx(self, spans)
+        return _SpanCtx(self, spans, ann)
 
     def emit(self, name: str, trace: Optional[str], t0: float, t1: float,
              **attrs):
@@ -182,3 +204,26 @@ def get_tracer() -> Tracer:
 def query_trace(qid) -> str:
     """Canonical trace id for a cluster Query: ``q<qid>``."""
     return f"q{qid}"
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_WATCHING = False
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event == _COMPILE_EVENT and _TRACER.enabled:
+        t1 = perf_counter()
+        attrs = {"program": kw["fun_name"]} if "fun_name" in kw else {}
+        _TRACER.emit("compile", None, t1 - secs, t1, **attrs)
+
+
+def watch_compiles() -> None:
+    """Register, once per process, the ``jax.monitoring`` listener that
+    records a retroactive ``compile`` span (attribute ``program``) for
+    each backend compile made while tracing is on."""
+    global _WATCHING
+    if _WATCHING:
+        return
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _WATCHING = True

@@ -58,6 +58,25 @@ from repro.serving.sampling import GenerationParams, sample_token
 _RECURRENT_KINDS = ("mlstm", "slstm", "hymba")
 _MIN_BUCKET = 8
 
+# The layer of each jitted serving program, by the ``__name__`` of the
+# function ``ServeEngine`` jits.  A profiler trace names each program
+# execution ``jit_<__name__>(<fingerprint>)`` on its ``XLA Modules``
+# line, so this table is how device time is read by layer.
+PROGRAM_LAYERS = {
+    "decode_step": "decode",
+    "_decode_loop_impl": "decode",
+    "_decode_cont_impl": "decode",
+    "_prefill_sample_impl": "prefill",
+    "_prefill_chunk_impl": "prefill",
+    "_refill_impl": "prefill",
+    "_paged_prefill_chunk_impl": "prefill",
+    "_paged_refill_impl": "prefill",
+    "_paged_prefix_prefill_impl": "prefill",
+    "_fresh_cache_impl": "other",
+    "_paged_fresh_cache_impl": "other",
+    "_paged_copy_block_impl": "other",
+}
+
 
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_len: int = 512,
@@ -65,8 +84,7 @@ class ServeEngine:
                  moe_capacity_factor: Optional[float] = None,
                  prefill_chunk: Optional[int] = None,
                  paged: bool = False, block_size: int = 16,
-                 num_blocks: Optional[int] = None,
-                 profile: Optional[str] = None):
+                 num_blocks: Optional[int] = None):
         cf = moe_capacity_factor
         if cf is None and cfg.moe is not None:
             cf = float(cfg.moe.num_experts)   # dropless at serving sizes
@@ -76,10 +94,6 @@ class ServeEngine:
         self.max_len = max_len
         self.batch_size = batch_size
         self.pad_id = pad_id
-        # jax.profiler hook: with profile=<logdir> set, the schedulers
-        # bracket their runs with start_profile()/stop_profile() so
-        # device traces align with host spans (docs/OBSERVABILITY.md)
-        self.profile_dir = profile
         # paged KV: full-attention K/V lives in a shared pool of
         # ``num_blocks`` blocks of ``block_size`` tokens addressed
         # through per-row block tables (see models/cache.py); rows then
@@ -665,21 +679,6 @@ class ServeEngine:
 
     # ----------------------------------------------------------------- public
 
-    def start_profile(self) -> bool:
-        """Begin a ``jax.profiler`` device trace into ``profile_dir``
-        (no-op unless the engine was built with ``profile=...`` and no
-        trace is already live)."""
-        if not self.profile_dir:
-            return False
-        from repro.obs import recorder as obs_recorder
-        return obs_recorder.start_device_profile(self.profile_dir)
-
-    def stop_profile(self) -> bool:
-        if not self.profile_dir:
-            return False
-        from repro.obs import recorder as obs_recorder
-        return obs_recorder.stop_device_profile()
-
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, key=None,
                  eos_id: Optional[int] = None,
@@ -1157,10 +1156,11 @@ class ContinuousSession:
         table_row[:len(ids)] = ids
         toks = np.full((1, L0), self.eng.pad_id, np.int32)
         toks[0, pad0:] = list(prefix)
-        self.cache, snap = self.eng._paged_prefix_prefill(
-            self.eng.params, jnp.asarray(toks), self.cache,
-            jnp.asarray(table_row), jnp.int32(L0), jnp.int32(pad0),
-            self.eng._paged_zero_row_state())
+        with obs_trace.get_tracer().span("prefix_prefill", tokens=p):
+            self.cache, snap = self.eng._paged_prefix_prefill(
+                self.eng.params, jnp.asarray(toks), self.cache,
+                jnp.asarray(table_row), jnp.int32(L0), jnp.int32(pad0),
+                self.eng._paged_zero_row_state())
         return PrefixEntry(block_ids=list(ids), length=L0, pad=pad0,
                            row_state=snap)
 
@@ -1182,6 +1182,7 @@ class ContinuousSession:
         tr = obs_trace.get_tracer()
         sp = obs_trace.NULL_SPAN
         if tr.enabled:
+            tstep0 = self.tstep
             tif = int(self.lengths[live].sum()) if self.paged \
                 else int(live.sum()) * self.length
             sp = tr.span("decode_segment",
@@ -1228,5 +1229,7 @@ class ContinuousSession:
             self.done = done_new
             self.idx = idx_new.astype(np.int32)
             self.segments += 1
-            sp.set(finished=len(events), tstep=self.tstep)
+            if tr.enabled:
+                sp.set(finished=len(events), tstep=self.tstep,
+                       steps=self.tstep - tstep0)
         return events

@@ -176,12 +176,8 @@ class RequestQueue:
     def run(self) -> Dict[int, List[int]]:
         """Drain the queue; returns {rid: generated tokens} for every
         completed request (including ones finished in earlier steps)."""
-        self.engine.start_profile()
-        try:
-            while self._pending:
-                self.step()
-        finally:
-            self.engine.stop_profile()
+        while self._pending:
+            self.step()
         return {rid: c.tokens for rid, c in self._done.items()}
 
     def result(self, rid: int) -> Completion:
@@ -590,7 +586,6 @@ class ContinuousQueue:
                 r.rid, [], len(r.prompt), r.budget, slot,
                 session.frames, ttft, ttft)
 
-        self.engine.start_profile()
         try:
             while not targets <= self._finished:
                 if session.active():
@@ -674,7 +669,6 @@ class ContinuousQueue:
                 if not self._pending and not session.active():
                     break   # wait_for named rids this queue never saw
         finally:
-            self.engine.stop_profile()
             if not self.standing and targets - self._finished:
                 # aborted mid-run (e.g. paged stall): a per-run queue
                 # cannot resume a half-drained session on the next run

@@ -1,11 +1,13 @@
 """Observability layer: span nesting + causal order across a full
-paged+federated request, metrics snapshot/delta, flight-recorder ring
-wraparound, and the disabled-mode no-op guarantee (zero events, zero
-clock reads on the decode segment path)."""
+paged+federated request, the profiler annotation each live span enters,
+compile spans, metrics snapshot/delta, flight-recorder ring wraparound,
+and the disabled-mode no-op guarantee (zero events, zero clock reads,
+zero annotations on the decode segment path)."""
 import json
 import os
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -123,6 +125,67 @@ def test_span_nesting_and_retroactive_emit(tmp_path):
     assert seg1["parent"] == root["id"] and seg2["parent"] is None
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation made, entered and exited."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kw):
+        log = self.log
+
+        class Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+                return False
+
+        log.append(("make", name))
+        return Ann()
+
+
+def test_enabled_span_enters_one_annotation(monkeypatch):
+    anns = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", anns)
+    obs.enable(capacity=64)
+    try:
+        tr = obs.get_tracer()
+        with tr.span("request", trace="r1"):
+            # batched: one annotation for all three traces
+            with tr.span("decode_segment", traces=["r1", "r2", "r3"],
+                         rows=3):
+                pass
+            tr.emit("queue_wait", "r1", 1.0, 2.0)     # retroactive: none
+    finally:
+        obs.disable()
+    assert anns.log == [
+        ("make", "obs.request"), ("enter", "obs.request"),
+        ("make", "obs.decode_segment"), ("enter", "obs.decode_segment"),
+        ("exit", "obs.decode_segment"), ("exit", "obs.request")]
+
+
+def test_backend_compile_becomes_a_compile_span():
+    rec = obs.enable(capacity=64)
+    try:
+        def tripled_plus_seven(x):
+            return 3 * x + 7
+
+        jax.jit(tripled_plus_seven)(np.arange(5.0)).block_until_ready()
+    finally:
+        obs.disable()
+    comp = [e for e in rec.events() if e["name"] == "compile"]
+    assert any(e["attrs"]["program"] == "jit(tripled_plus_seven)"
+               and e["t1"] >= e["t0"] for e in comp), comp
+    # off again: a compile records nothing
+    n = len(rec.events())
+    jax.jit(lambda x: x - 11)(np.arange(3.0)).block_until_ready()
+    assert len(rec.events()) == n
+
+
 # ------------------------------------------------------- live integration
 
 
@@ -209,7 +272,8 @@ def test_traced_slot_metrics_rollup(obs_cluster):
 
 def test_disabled_mode_never_reads_clock(obs_cluster, monkeypatch):
     """With tracing off, the serving path must not touch the tracer's
-    clock or allocate span state — the instrument is free when unused."""
+    clock, make a profiler annotation or allocate span state — the
+    instrument is free when unused."""
     import repro.obs.trace as trace_mod
     nodes, _, _ = obs_cluster
     assert not obs.enabled()
@@ -217,7 +281,11 @@ def test_disabled_mode_never_reads_clock(obs_cluster, monkeypatch):
     def boom():
         raise AssertionError("perf_counter read on the disabled path")
 
+    def no_annotation(name, **kw):
+        raise AssertionError(f"annotation {name} made on the disabled path")
+
     monkeypatch.setattr(trace_mod, "perf_counter", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", no_annotation)
     tr = obs.get_tracer()
     assert tr.span("decode_segment", traces=["a", "b"]) is obs.NULL_SPAN
     assert tr.now() == 0.0
@@ -235,3 +303,33 @@ def test_disabled_mode_never_reads_clock(obs_cluster, monkeypatch):
     sess.release()
     assert done == 2
     assert tr.recorder is None
+
+
+def test_traced_slot_names_each_node_stage(obs_cluster, tmp_path):
+    """Dispatch, tokenisation, the engine run, scoring and feedback each
+    have a span of their own, under the right parent, and every decode
+    segment carries the decode-loop steps it ran."""
+    nodes, rec, tids = obs_cluster
+    events = rec.events()
+    by_id = {e["id"]: e for e in events if e["kind"] == "span"}
+    for tid in tids:
+        spans = {e["name"]: e for e in events
+                 if e["kind"] == "span" and e["trace"] == tid}
+        root = spans["request"]
+        slot = spans["node_slot"]
+        assert slot["parent"] == root["id"]
+        assert slot["attrs"]["node"] in (0, 1)
+        for name in ("retrieve", "tokenize", "generate", "score"):
+            assert spans[name]["parent"] == slot["id"], name
+        assert spans["tokenize"]["t1"] <= spans["generate"]["t0"] \
+            <= spans["generate"]["t1"] <= spans["score"]["t0"]
+        assert by_id[spans["detokenize"]["parent"]]["name"] == "score"
+        assert by_id[spans["prefill"]["parent"]]["name"] == "generate"
+        assert spans["feedback"]["parent"] == root["id"]
+        assert spans["feedback"]["t0"] >= slot["t1"]
+    segs = [e for e in events if e["kind"] == "span"
+            and e["name"] == "decode_segment"]
+    assert segs and all(e["attrs"]["steps"] >= 1 for e in segs)
+    # the dump with the new spans still passes the CI gate
+    path = rec.export_jsonl(str(tmp_path / "stages.jsonl"))
+    assert trace_report.main([path, "--check"]) == 0

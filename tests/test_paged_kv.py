@@ -338,3 +338,19 @@ def test_paged_attention_all_blocks_unallocated_row():
     assert np.isfinite(np.asarray(out_k)).all()
     np.testing.assert_allclose(np.asarray(out_k[0]), np.asarray(out[0]),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_program_layer_table_names_every_jitted_program(key):
+    """``PROGRAM_LAYERS`` reads device time by layer from the programs'
+    names: every program a paged engine jits is in it, and every name in
+    it is a jitted program's, so a rename fails here, not in a reader."""
+    from repro.serving.engine import PROGRAM_LAYERS
+    eng = make_paged_engine("llama3-8b", key)
+    jitted = {v.__name__ for v in vars(eng).values()
+              if type(v).__name__ == "PjitFunction"}
+    assert jitted == set(PROGRAM_LAYERS)
+    assert set(PROGRAM_LAYERS.values()) <= {"decode", "prefill", "other"}
+    assert PROGRAM_LAYERS["_decode_cont_impl"] == "decode"
+    for name in ("_paged_refill_impl", "_paged_prefix_prefill_impl",
+                 "_paged_prefill_chunk_impl"):
+        assert PROGRAM_LAYERS[name] == "prefill"
