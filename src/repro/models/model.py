@@ -209,8 +209,8 @@ class Model:
                               window, first, pos_shift, ctx=None):
         """Chunk-mode attention: the segment's queries attend to (cached
         past ⊕ current segment), then the segment's K/V are persisted —
-        so a prompt is absorbed through one static [B, C] program C
-        tokens at a time.  Returns (attn, new_kv_stack).
+        so a prompt is absorbed pass by pass through static-shape
+        programs (see ``prefill_chunk``).  Returns (attn, new_kv_stack).
 
         Paged mode (``ctx["paged"]``): ``start`` is per-row [B]; full
         "attn" slots live in the shared block pool and are read through
@@ -641,14 +641,17 @@ class Model:
 
     def prefill_chunk(self, params, batch: dict, cache: dict
                       ) -> Tuple[jax.Array, dict]:
-        """Absorb one fixed-size prompt chunk into the cache.
+        """Absorb one pass of prompt chunks ([B, S] tokens) into the
+        cache.
 
         Like ``prefill`` but (a) queries attend to ALL cached K/V —
-        earlier chunks included — so a prompt runs through one static
-        [B, C] program C tokens at a time, (b) recurrent state updates
-        are masked at pad positions (left-padding to a chunk multiple is
-        numerically exact), and (c) ``batch["positions"]`` are per-row
-        *relative* — counted from the row's first real token
+        earlier passes included — so a prompt padded to a multiple of
+        the chunk C runs through static-shape passes: a frame's [B, C]
+        program C tokens at a time, a staging prefill several chunks a
+        pass (``ServeEngine.staging_passes``), (b) recurrent state
+        updates are masked at pad positions (left-padding to a chunk
+        multiple is numerically exact), and (c) ``batch["positions"]``
+        are per-row *relative* — counted from the row's first real token
         (``cache["first"]``), -1 at pads — while cache slots stay keyed
         by the shared absolute ``cache["length"]``, so RoPE / learned
         position embeddings match an unpadded solo run regardless of
@@ -656,7 +659,7 @@ class Model:
         (last-position logits [B,V], cache).
 
         Known redundancy: encoder-decoder configs re-run the encoder
-        per chunk (enc K/V are rewritten idempotently) — a static
+        per pass (enc K/V are rewritten idempotently) — a static
         first-chunk flag would double the compile count, and the
         serving path feeds zero frames, so the repeated pass is cheap;
         revisit if real audio frames ever reach continuous serving."""

@@ -30,15 +30,18 @@ would recompile the decode loop per length).
 ``generate_reference`` keeps the original per-token Python loop (one
 host sync per token) for parity tests and the throughput benchmark.
 
-Continuous batching (``prefill_chunk`` set): prompts are absorbed C
-tokens at a time through one static [B, C] chunked-prefill program
-(``Model.prefill_chunk``) instead of a per-bucket/per-length fused
-prefill — killing the per-exact-prompt-length recompile on recurrent
-architectures — and ``ContinuousSession`` refills individual decode
-slots the moment a row finishes (EOS / budget) by prefilling the next
-request into a single-row staging cache and swapping it in with
-``cache.insert_row``, instead of waiting for the whole wave.  See
-docs/ARCHITECTURE.md ("Continuous batching").
+Continuous batching (``prefill_chunk`` set): prompts are padded to a
+multiple of C tokens and absorbed through ``Model.prefill_chunk``
+instead of a per-bucket/per-length fused prefill — killing the
+per-exact-prompt-length recompile on recurrent architectures — and
+``ContinuousSession`` refills individual decode slots the moment a row
+finishes (EOS / budget) by prefilling the next request into a
+single-row staging cache and swapping it in with ``cache.insert_row``,
+instead of waiting for the whole wave.  A frame's batch goes through
+one static [B, C] program C tokens at a time; a staging prefill runs
+its k·C tokens in passes of up to ``PREFILL_PASS_MAX`` tokens (see
+``ServeEngine.staging_passes``).  See docs/ARCHITECTURE.md
+("Continuous batching").
 """
 from __future__ import annotations
 
@@ -57,6 +60,15 @@ from repro.serving.sampling import GenerationParams, sample_token
 
 _RECURRENT_KINDS = ("mlstm", "slstm", "hymba")
 _MIN_BUCKET = 8
+
+# Longest pass, in tokens, of a staging prefill (refill, prefix
+# prefill).  A pass reads every weight once, so short passes are
+# weight-read bound: on a TPU v5e (197 TFLOP/s bf16, 819 GB/s) the
+# ridge point is ~240 FLOP/byte, and a bf16 matmul turns compute-bound
+# at ~256 rows.  1024 rows sit 4x past the ridge, so the per-pass weight
+# read and block-table gather are a small part of each pass, while
+# activations stay O(1024 x d_ff) however long the context is.
+PREFILL_PASS_MAX = 1024
 
 # The layer of each jitted serving program, by the ``__name__`` of the
 # function ``ServeEngine`` jits.  A profiler trace names each program
@@ -141,6 +153,19 @@ class ServeEngine:
                 raise ValueError("chunked prefill is unsupported for "
                                  "pos_embedding='sinusoidal' (the table "
                                  "ignores the chunk offset)")
+            # chunks a staging-prefill pass: the most that fit in
+            # PREFILL_PASS_MAX and, where a layer is windowed, in the
+            # window (a longer pass would overwrite its own rolling-
+            # buffer writes).  MoE layers that can drop tokens keep one
+            # chunk a pass: their expert capacity is counted per pass.
+            g = PREFILL_PASS_MAX // prefill_chunk
+            if any(kind in ("local", "hymba")
+                   for _, kind in self.model.slots):
+                g = min(g, cfg.sliding_window // prefill_chunk)
+            if cfg.moe is not None \
+                    and self.model.moe_cf < cfg.moe.num_experts:
+                g = 1
+            self._pass_chunks = max(1, g)
             self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
                                           donate_argnums=(2,))
             self._decode_cont = jax.jit(self._decode_cont_impl,
@@ -302,28 +327,29 @@ class ServeEngine:
         return cache
 
     def _chunk_step(self, params, toks, cache, l_end=None):
-        """One [B, C] chunk of the chunked prefill: derive per-row
+        """One [B, S] pass of the chunked prefill (a frame's C-token
+        chunk, or a staging pass of several chunks): derive per-row
         RELATIVE positions (counted from ``cache['first']``, -1 at pads)
         at the cache's current absolute offset, then
-        ``Model.prefill_chunk``.  The offset is traced, so every chunk
-        of every prompt length reuses one compiled program per batch
-        shape.  ``l_end`` (paged caches: per-row lengths, right-padded
-        chunk tails) additionally masks columns at/after the prompt end
-        and points the logits read at the last real column."""
-        B, C = toks.shape
+        ``Model.prefill_chunk``.  The offset is traced, so every pass of
+        every prompt length reuses one compiled program per shape.
+        ``l_end`` (paged caches: per-row lengths, right-padded chunk
+        tails) additionally masks columns at/after the prompt end and
+        points the logits read at the last real column."""
+        B, S = toks.shape
         first = cache["first"]
         abs_pos = jnp.reshape(cache["length"], (-1, 1)) \
-            + jnp.arange(C, dtype=jnp.int32)[None, :]
+            + jnp.arange(S, dtype=jnp.int32)[None, :]
         valid = abs_pos >= first[:, None]
         if l_end is not None:
             valid = valid & (abs_pos < l_end)
         pos = jnp.where(valid, abs_pos - first[:, None], -1)
         if self.cfg.use_mrope:
-            pos = jnp.broadcast_to(pos, (3, B, C))
+            pos = jnp.broadcast_to(pos, (3, B, S))
         batch = {"tokens": toks, "positions": pos}
         if l_end is not None:
             batch["last_col"] = jnp.clip(
-                l_end - 1 - jnp.reshape(cache["length"], (-1,)), 0, C - 1)
+                l_end - 1 - jnp.reshape(cache["length"], (-1,)), 0, S - 1)
         if self.cfg.is_encoder_decoder:
             batch["encoder_frames"] = jnp.zeros(
                 (B, self.cfg.encoder_seq_len, self.cfg.d_model),
@@ -335,27 +361,16 @@ class ServeEngine:
 
     def _refill_impl(self, params, toks, tok, cache, done, remaining, idx,
                      slot, p_len, budget, key, gp: GenerationParams):
-        """Fused mid-frame refill — ONE dispatch per slot swap: chunk-
-        prefill ``toks`` ([1, k*C], left-padded) into a fresh staging
+        """Fused mid-frame refill — ONE dispatch per slot swap: prefill
+        ``toks`` ([1, k*C], left-padded) into a fresh staging
         cache whose frames end at the live cache's position, sample the
         row's first token, ``insert_row`` the staging state into
         ``slot``, and flip the slot's decode carry (done / remaining /
         idx) live.  Compiled once per chunk count k."""
-        C = self.prefill_chunk
-        k = toks.shape[1] // C
         d = cache["length"]
         staging = self._fresh_cache_impl((d - p_len)[None],
                                          d - toks.shape[1])
-
-        def chunk(carry, j):
-            _, stg = carry
-            tc = jax.lax.dynamic_slice_in_dim(toks, j * C, C, axis=1)
-            logits, stg = self._chunk_step(params, tc, stg)
-            return (logits.astype(jnp.float32), stg), None
-
-        logits0 = jnp.zeros((1, self.cfg.vocab_size), jnp.float32)
-        (logits, staging), _ = jax.lax.scan(chunk, (logits0, staging),
-                                            jnp.arange(k))
+        logits, staging = self._staging_prefill(params, toks, staging)
         tok_new = sample_token(logits, gp, key, 0)
         cache = cache_lib.insert_row(cache, staging, jnp.int32(0), slot)
         tok = jax.lax.dynamic_update_slice(tok, tok_new, (slot, 0))
@@ -415,20 +430,39 @@ class ServeEngine:
             stg["enc"] = row_state["enc"]
         return stg
 
-    def _paged_scan_chunks(self, params, toks, staging, l_end):
-        """Chunk-scan ``toks`` [1, k*C] through the staging row; returns
-        (last chunk's logits, staging)."""
-        C = self.prefill_chunk
+    def staging_passes(self, k: int) -> Tuple[int, int, int]:
+        """Pass layout of a staging prefill of ``k`` chunks, in chunks:
+        ``(n, g, r)`` = ``n`` full passes of ``g`` chunks, then one pass
+        of ``r`` chunks when ``r > 0``.  Static per ``k``, so each chunk
+        count still compiles one program, and no pass is padded past the
+        prompt's own chunks."""
+        g = min(self._pass_chunks, k)
+        n, r = divmod(k, g)
+        return n, g, r
 
-        def chunk(carry, j):
-            _, stg = carry
-            tc = jax.lax.dynamic_slice_in_dim(toks, j * C, C, axis=1)
-            logits, stg = self._chunk_step(params, tc, stg, l_end=l_end)
-            return (logits.astype(jnp.float32), stg), None
+    def _staging_prefill(self, params, toks, staging, l_end=None):
+        """Prefill ``toks`` [1, k*C] through the staging row in the
+        passes of ``staging_passes(k)``; returns (the last pass's logits
+        [1, V] f32, staging)."""
+        n, g, r = self.staging_passes(toks.shape[1] // self.prefill_chunk)
+        P = g * self.prefill_chunk
 
-        logits0 = jnp.zeros((1, self.cfg.vocab_size), jnp.float32)
-        (logits, staging), _ = jax.lax.scan(
-            chunk, (logits0, staging), jnp.arange(toks.shape[1] // C))
+        def one(stg, t):
+            logits, stg = self._chunk_step(params, t, stg, l_end=l_end)
+            return logits.astype(jnp.float32), stg
+
+        if n == 1:
+            logits, staging = one(staging, toks[:, :P])
+        else:
+            def body(carry, j):
+                t = jax.lax.dynamic_slice_in_dim(toks, j * P, P, axis=1)
+                return one(carry[1], t), None
+
+            logits0 = jnp.zeros((1, self.cfg.vocab_size), jnp.float32)
+            (logits, staging), _ = jax.lax.scan(
+                body, (logits0, staging), jnp.arange(n))
+        if r:
+            logits, staging = one(staging, toks[:, n * P:])
         return logits, staging
 
     def _paged_merge_staging(self, cache, staging, slot, l_end, first0,
@@ -475,14 +509,14 @@ class ServeEngine:
           the prefix's pad offset, ``row_state`` the prefix snapshot;
           ``table_row`` already shares the prefix's pool blocks.
 
-        Chunk-prefills into the staging row, samples the first token,
+        Prefills into the staging row, samples the first token,
         merges into ``slot`` and flips the decode carry live.  Only
         traced scalars differ between flavors, so both compile once per
         chunk count."""
         staging = self._paged_row_staging(cache, row_state, table_row,
                                           length0, first0)
-        logits, staging = self._paged_scan_chunks(params, toks, staging,
-                                                  l_end)
+        logits, staging = self._staging_prefill(params, toks, staging,
+                                                l_end)
         tok_new = sample_token(logits, gp, key, 0)
         cache = self._paged_merge_staging(cache, staging, slot, l_end,
                                           first0, table_row)
@@ -504,7 +538,7 @@ class ServeEngine:
         needs to resume from position ``l_end``."""
         staging = self._paged_row_staging(cache, row_state, table_row,
                                           jnp.int32(0), first0)
-        _, staging = self._paged_scan_chunks(params, toks, staging, l_end)
+        _, staging = self._staging_prefill(params, toks, staging, l_end)
         new_slots = dict(cache["slots"])
         for name in self._pooled:
             new_slots[name] = staging["slots"][name]
@@ -802,6 +836,10 @@ class ContinuousSession:
         self.frames = 0
         self.segments = 0
         self.refills = 0
+        # staging prefills (refill, prefix prefill): model passes
+        # dispatched and tokens staged, pads included
+        self.prefill_passes = 0
+        self.prefill_tokens = 0
         # slot -> request trace id (set by the scheduler at admission);
         # decode-segment spans and prefix-cache events attribute to it
         self.traces: Dict[int, Optional[str]] = {}
@@ -969,6 +1007,15 @@ class ContinuousSession:
 
     # ------------------------------------------------------------ admission
 
+    def _count_staged(self, n_tokens: int) -> int:
+        """Count a staging prefill of ``n_tokens`` (a chunk multiple);
+        returns the model passes it dispatches."""
+        n, _, r = self.eng.staging_passes(n_tokens // self.C)
+        passes = n + (r > 0)
+        self.prefill_passes += passes
+        self.prefill_tokens += n_tokens
+        return passes
+
     def _chunked_prefill(self, cache, toks: np.ndarray):
         logits = None
         for j in range(toks.shape[1] // self.C):
@@ -1066,6 +1113,7 @@ class ContinuousSession:
             padded = self._padded(p)
             toks = np.full((1, padded), self.eng.pad_id, np.int32)
             toks[0, padded - p:] = list(prompt)
+            self._count_staged(padded)
             (self.tok, self.cache, self._done_d, self._rem_d,
              self._idx_d) = self.eng._refill(
                 self.eng.params, jnp.asarray(toks), self.tok, self.cache,
@@ -1083,6 +1131,7 @@ class ContinuousSession:
 
     def _dispatch_paged_refill(self, toks, slot, budget, table_row,
                                row_state, length0, l_end, first0) -> None:
+        self._count_staged(toks.shape[1])
         (self.tok, self.cache, self._done_d, self._rem_d,
          self._idx_d) = self.eng._paged_refill(
             self.eng.params, jnp.asarray(toks), self.tok, self.cache,
@@ -1156,7 +1205,9 @@ class ContinuousSession:
         table_row[:len(ids)] = ids
         toks = np.full((1, L0), self.eng.pad_id, np.int32)
         toks[0, pad0:] = list(prefix)
-        with obs_trace.get_tracer().span("prefix_prefill", tokens=p):
+        passes = self._count_staged(L0)
+        with obs_trace.get_tracer().span("prefix_prefill", tokens=p,
+                                         passes=passes):
             self.cache, snap = self.eng._paged_prefix_prefill(
                 self.eng.params, jnp.asarray(toks), self.cache,
                 jnp.asarray(table_row), jnp.int32(L0), jnp.int32(pad0),

@@ -216,6 +216,8 @@ class ContinuousStats:
     shed_hint_drops: int = 0      # requests dropped by the SLO shed hint
     cow_forks: int = 0            # paged copy-on-write block forks
     kv_exhaustions: int = 0       # paged pool-exhaustion waits
+    prefill_passes: int = 0       # model passes of staging prefills
+    prefill_tokens: int = 0       # tokens staged by them, pads included
     ttft_s: List[float] = field(default_factory=list)
     latency_s: List[float] = field(default_factory=list)
 
@@ -227,7 +229,8 @@ class ContinuousStats:
     COUNTERS = ("requests", "tokens_out", "frames", "segments", "refills",
                 "prefix_hits", "prefix_misses", "prefix_evictions",
                 "admission_skips", "shed", "shed_hint_drops",
-                "cow_forks", "kv_exhaustions")
+                "cow_forks", "kv_exhaustions", "prefill_passes",
+                "prefill_tokens")
 
     def snapshot(self) -> Dict[str, int]:
         """Point-in-time copy of the monotone counters (plus the lengths
@@ -514,7 +517,10 @@ class ContinuousQueue:
         run() entry — a standing session outlives the run, so only the
         run's deltas roll into ``self.stats``."""
         base = {"frames": session.frames, "segments": session.segments,
-                "refills": session.refills, "forks": 0, "exhaustions": 0,
+                "refills": session.refills,
+                "prefill_passes": session.prefill_passes,
+                "prefill_tokens": session.prefill_tokens,
+                "forks": 0, "exhaustions": 0,
                 "prefix_hits": 0, "prefix_misses": 0, "prefix_evictions": 0}
         if session.paged:
             base["forks"] = session.allocator.forks
@@ -627,12 +633,19 @@ class ContinuousQueue:
                         self._pending.remove(r)
                         if tr.enabled:
                             session.traces[slot] = r.trace
+                        passes0 = session.prefill_passes
+                        tokens0 = session.prefill_tokens
                         with tr.span("prefill", trace=r.trace,
                                      mode="refill", slot=slot,
                                      prompt_len=len(r.prompt),
-                                     prefix_len=r.prefix_len):
+                                     prefix_len=r.prefix_len) as sp:
                             session.refill(slot, r.prompt, r.budget,
                                            prefix_len=r.prefix_len or None)
+                            # the admission's staging passes, a prefix
+                            # prefill's included, and the tokens staged
+                            sp.set(
+                                passes=session.prefill_passes - passes0,
+                                staged_tokens=session.prefill_tokens - tokens0)
                         admitted += 1
                         admit(slot, r)
                 if self._pending and not admitted and not session.active():
@@ -679,6 +692,8 @@ class ContinuousQueue:
         st.frames += s.frames - sbase["frames"]
         st.segments += s.segments - sbase["segments"]
         st.refills += s.refills - sbase["refills"]
+        st.prefill_passes += s.prefill_passes - sbase["prefill_passes"]
+        st.prefill_tokens += s.prefill_tokens - sbase["prefill_tokens"]
         if paged:
             st.cow_forks += s.allocator.forks - sbase["forks"]
             st.kv_exhaustions += \

@@ -18,6 +18,7 @@ from repro.kernels import ops, ref
 from repro.models import Model
 from repro.models import cache as cache_lib
 from repro.serving import ContinuousQueue, GenerationParams, ServeEngine
+from repro.serving import engine as engine_mod
 
 
 def make_paged_engine(arch, key, batch_size=2, max_len=96, prefill_chunk=8,
@@ -112,16 +113,29 @@ def test_block_allocator_recycle_no_leak():
 # ------------------------------------------------------------------ parity
 
 
-@pytest.mark.parametrize("arch", [
-    "llama3-8b",                      # full (pooled) attention
-    "gemma2-9b",                      # rolling local + pooled + softcap
-    "xlstm-350m",                     # recurrent only
-    "hymba-1.5b",                     # rolling attn + mamba hybrid
-    "whisper-base",                   # enc-dec, learned positions
+@pytest.mark.parametrize("arch,long_ctx", [
+    pytest.param("llama3-8b", False, id="llama3-8b"),   # pooled attention
+    pytest.param("gemma2-9b", False, id="gemma2-9b"),   # local + pooled
+    pytest.param("xlstm-350m", False, id="xlstm-350m"),  # recurrent only
+    pytest.param("hymba-1.5b", False, id="hymba-1.5b"),  # window + mamba
+    pytest.param("whisper-base", False, id="whisper-base"),  # enc-dec
+    pytest.param("llama3-8b", True, id="llama3-8b-long"),
+    pytest.param("gemma2-9b", True, id="gemma2-9b-long"),
+    pytest.param("xlstm-350m", True, id="xlstm-350m-long"),
+    pytest.param("hymba-1.5b", True, id="hymba-1.5b-long"),
+    pytest.param("qwen2-moe-a2.7b", True, id="qwen2-moe-a2.7b-long"),
 ])
-def test_paged_parity_frame_refill_fork(arch, key):
+def test_paged_parity_frame_refill_fork(arch, long_ctx, key, monkeypatch):
     """One frame, a plain paged refill, and a prefix-cache fork must all
-    be token-exact against solo references — for every cache kind."""
+    be token-exact against solo references — for every cache kind.
+
+    ``long_ctx``: a 67-token context under 32-token staging passes (4
+    chunks of 8; 2 under the 16-token window of gemma2/hymba), so the
+    plain refill and the prefix prefill (9 chunks) each run a scan of
+    full passes and a remainder pass, the prefix ends mid-block, and the
+    windowed configs cross their window."""
+    if long_ctx:
+        monkeypatch.setattr(engine_mod, "PREFILL_PASS_MAX", 32)
     eng = make_paged_engine(arch, key)
     if arch == "whisper-base":        # learned positions: pow-2 prompts
         ctx = [5, 6, 7, 2, 3, 4, 1, 2]
@@ -129,6 +143,9 @@ def test_paged_parity_frame_refill_fork(arch, key):
     else:
         ctx = [5, 6, 7, 2, 3, 4, 1, 2, 9, 9, 3]
         q1, q2 = [4, 4, 1], [7, 8, 2]
+    if long_ctx:
+        ctx = [1 + (5 * i + i // 7) % 11 for i in range(67)]
+        assert eng.staging_passes(9)[2] > 0
     budget = 5
     refs = solo_refs(eng, [ctx + q1, ctx + q2], budget)
     sess = eng.continuous_session(GenerationParams(max_new_tokens=budget),
