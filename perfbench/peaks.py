@@ -7,11 +7,13 @@ v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM per chip).
 The work functions count what the configuration's arithmetic needs for
 the tokens actually prefilled and generated, at the configuration's
 dtype, independent of how the program implements it: a later change of
-pool dtype, padding or kernel moves a share, never the count.
+pool dtype, padding or kernel moves a share, never the count.  Each
+layer's share of the count is its kind's module's in ``perfbench/arch/``;
+a kind that no module declares is an error, never a count of 0.
 """
 from __future__ import annotations
 
-import math
+from perfbench import arch
 
 PEAKS = {
     # device_kind: (bf16 FLOP/s, HBM bytes/s, source)
@@ -28,57 +30,45 @@ def peaks(device_kind: str):
     return f, b
 
 
-def _hd(m: dict) -> int:
+def head_dim(m: dict) -> int:
     return m.get("head_dim") or m["d_model"] // m["num_heads"]
+
+
+def _layers(m: dict):
+    """(kind, repeats) for each slot of the layer pattern, each kind
+    known to ``perfbench/arch/``."""
+    pat = m["layer_pattern"]
+    reps = m["num_layers"] // len(pat)
+    return [(arch.kind(k), reps) for k in pat]
 
 
 def matmul_params(m: dict) -> int:
     """Weights one token passes through in the decoder stack (no
     embedding gather, no LM head)."""
-    d, f = m["d_model"], m["d_ff"]
-    hd = _hd(m)
-    per_kind = {}
-    attn = d * m["num_heads"] * hd * 2 + 2 * d * m["num_kv_heads"] * hd
-    mlp = 3 * d * f if m.get("mlp_type", "swiglu") != "none" else 0
-    per_kind["attn"] = per_kind["local"] = attn + mlp
-    if m.get("ssm"):
-        inner = m["ssm"]["expand"] * d
-        st = m["ssm"]["state_size"]
-        r = max(1, math.ceil(d / 16))
-        per_kind["hymba"] = attn + mlp + d * 2 * inner \
-            + inner * (r + 2 * st) + r * inner + inner * d
-    H = m["num_heads"]
-    per_kind["mlstm"] = d * H * hd * 4 + 2 * d * H + H * hd * d
-    per_kind["slstm"] = d * 4 * d + d * d
-    pat = m["layer_pattern"]
-    reps = m["num_layers"] // len(pat)
-    return reps * sum(per_kind[k] for k in pat)
+    return sum(reps * k.params(m) for k, reps in _layers(m))
 
 
 def token_flops(m: dict, ctx: int) -> float:
     """FLOPs of one token at context length ``ctx`` (tokens it attends
     to, itself included) through the decoder stack: 2 per weight, plus
-    attention scores and values over the live context (window-capped),
-    plus the recurrent state updates."""
-    hd = _hd(m)
-    H = m["num_heads"]
-    flops = 2.0 * matmul_params(m)
-    pat = m["layer_pattern"]
-    reps = m["num_layers"] // len(pat)
-    win = m.get("sliding_window")
-    for k in pat:
-        if k == "attn":
-            flops += reps * 4.0 * ctx * H * hd
-        elif k in ("local", "hymba"):
-            flops += reps * 4.0 * min(ctx, win or ctx) * H * hd
-        if k == "hymba":
-            inner = m["ssm"]["expand"] * m["d_model"]
-            flops += reps * 6.0 * inner * m["ssm"]["state_size"]
-        elif k == "mlstm":
-            flops += reps * 6.0 * H * hd * hd
-        elif k == "slstm":
-            flops += reps * 12.0 * m["d_model"]
-    return flops
+    what each layer's kind counts beyond that -- attention scores and
+    values over the live context (window-capped), recurrent state
+    updates."""
+    return _per_token(m)(ctx)
+
+
+def _per_token(m: dict):
+    """``token_flops`` of ``m`` as a function of the context, with the
+    layers looked up once."""
+    layers = _layers(m)
+    weights = 2.0 * sum(reps * k.params(m) for k, reps in layers)
+
+    def at(ctx: int) -> float:
+        flops = weights
+        for k, reps in layers:
+            flops += reps * k.flops(m, ctx)
+        return flops
+    return at
 
 
 def head_flops(m: dict) -> float:
@@ -93,27 +83,28 @@ def request_flops(m: dict, prefilled: int, prompt_len: int,
     prefilled again) plus ``generated`` tokens, of which all but the
     last were fed back through a decode step.  One LM head per prompt
     and per decode step."""
+    at = _per_token(m)
     total = 0.0
     for pos in range(prompt_len - prefilled, prompt_len):
-        total += token_flops(m, pos + 1)
+        total += at(pos + 1)
     for j in range(max(0, generated - 1)):
-        total += token_flops(m, prompt_len + j + 1)
+        total += at(prompt_len + j + 1)
     return total + head_flops(m) * max(1, generated)
 
 
 def prefix_flops(m: dict, prefix_len: int) -> float:
     """FLOPs of prefilling a shared context prefix once."""
-    return sum(token_flops(m, pos + 1) for pos in range(prefix_len))
+    at = _per_token(m)
+    return sum(at(pos + 1) for pos in range(prefix_len))
 
 
 def paged_kv_bytes(m: dict, prompt_len: int, generated: int,
                    itemsize: int = 2) -> float:
-    """Least bytes a paged decode read moves for one request: the live
-    K and V of every full-attention layer, at the model's dtype, for
+    """Least bytes a paged decode read moves for one request: what each
+    layer's kind reads through the paged pool per token of context (the
+    live K and V of a full-attention layer), at the model's dtype, for
     each decode step (context = prompt + tokens so far)."""
-    n_attn = m["num_layers"] // len(m["layer_pattern"]) \
-        * sum(1 for k in m["layer_pattern"] if k == "attn")
-    per_tok = n_attn * 2 * m["num_kv_heads"] * _hd(m) * itemsize
+    per_tok = sum(reps * k.kv_bytes(m, itemsize) for k, reps in _layers(m))
     steps = max(0, generated - 1)
     # contexts prompt+1 .. prompt+steps
     ctx_sum = steps * prompt_len + steps * (steps + 1) / 2

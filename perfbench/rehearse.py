@@ -7,7 +7,8 @@ reckoning per configuration.
 
 Programs: the paged prefix prefill at the longest prefix, the fork
 refill of a question suffix, the decode segment at its widest block
-table, and one layer of the plain reference in float32.  Nothing runs.
+table, and one layer of the plain reference in float32 for each layer
+kind, as its module in ``perfbench/arch/`` lays it out.  Nothing runs.
 """
 import os
 
@@ -38,7 +39,7 @@ def node_programs(node_spec: dict, traffic: dict, one_chip) -> dict:
     from repro.models import Model
     from repro.retrieval.encoder import TextEncoder
 
-    from perfbench import reference, spec
+    from perfbench import arch, reference, spec, weights
 
     def on_chip(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -69,9 +70,9 @@ def node_programs(node_spec: dict, traffic: dict, one_chip) -> dict:
     l0 = eng.cont_max_prompt_len(gen.max_new_tokens) - C
     out = {"batch": B, "prefill_chunk": C, "block_size": eng.block_size,
            "num_blocks": eng.num_blocks, "max_len": max_len}
-    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    wbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-    out["weights_bytes"], out["cache_bytes"] = weights, pool
+    out["weights_bytes"], out["cache_bytes"] = wbytes, pool
     progs = {
         "prefix_prefill": eng._paged_prefix_prefill.lower(
             params, s((1, l0)), cache, s((eng.nb_total,)), s(()), s(()),
@@ -91,14 +92,17 @@ def node_programs(node_spec: dict, traffic: dict, one_chip) -> dict:
         if name == "decode_segment":
             out[name]["tpu_custom_call"] = "tpu_custom_call" in \
                 compiled.as_text()
-    # one reference layer of each kind, float32, at the reference length
+    # one reference layer of each kind, float32, at the reference length,
+    # on the benchmark's own layout of that kind's block
     m = dict(node_spec["model"])
     m["head_dim"] = m.get("head_dim") or m["d_model"] // m["num_heads"]
     cj = json.dumps(m, sort_keys=True)
-    for i, kind in enumerate(cfg.layer_pattern):
-        p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape[1:], a.dtype, sharding=one_chip),
-            params["blocks"][f"s{i}_{kind}"])
+    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    for kind in arch.kinds(cfg.layer_pattern):
+        p = jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+            leaf[0], jnp.dtype(leaf[2]) if len(leaf) > 2 else dtype,
+            sharding=one_chip), arch.kind(kind).block(cfg),
+            is_leaf=weights._is_leaf)
         x = s((max_len, cfg.d_model), jnp.float32)
         out[f"reference_{kind}"] = _mem(reference._layer_jit.lower(
             p, x, kind=kind, cj=cj, quant=None).compile())

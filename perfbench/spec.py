@@ -11,8 +11,10 @@ entries; nothing here changes.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
+import typing
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Callable, Dict, List
@@ -75,12 +77,26 @@ def load_cell(root: Path, workload: str) -> Cell:
 
 def model_config(node: dict):
     """The node's ``ModelConfig`` exactly as its configuration file
-    states it (the file, not the program's registry, is what is run)."""
-    from repro.configs.base import ModelConfig, SSMConfig
+    states it (the file, not the program's registry, is what is run).
+    Each field is converted by its annotation: a nested group (``ssm``,
+    ``moe``) to its dataclass, a list to a tuple."""
+    from repro.configs.base import ModelConfig
+    hints = typing.get_type_hints(ModelConfig)
     fields = dict(node["model"])
-    if fields.get("ssm") is not None:
-        fields["ssm"] = SSMConfig(**fields["ssm"])
-    for k in ("layer_pattern", "mrope_sections"):
-        if k in fields:
-            fields[k] = tuple(fields[k])
+    for k, v in fields.items():
+        if k not in hints:          # ModelConfig refuses it below
+            continue
+        if isinstance(v, dict):
+            fields[k] = _nested(hints[k])(**v)
+        elif isinstance(v, list) and typing.get_origin(hints[k]) is tuple:
+            fields[k] = tuple(v)
     return ModelConfig(**fields)
+
+
+def _nested(hint):
+    """The dataclass a field annotated ``hint`` (``X`` or
+    ``Optional[X]``) holds."""
+    for t in (hint, *typing.get_args(hint)):
+        if dataclasses.is_dataclass(t):
+            return t
+    raise TypeError(f"{hint} holds no dataclass")

@@ -53,15 +53,17 @@ def tiny_traffic() -> dict:
 def make_copy(tmp: Path, archs=("attn", "xlstm"), knee: float = 1.5,
               limits=None) -> Path:
     """A copy of BENCHMARK.json and perfbench/ under ``tmp`` with the cell
-    ``tiny.rag`` added by data files only."""
+    ``tiny.rag`` added by data files only.  ``archs`` names models of
+    ``TINY_MODELS`` or gives a model's dict."""
     root = tmp / "checkout"
     shutil.copytree(ROOT / "perfbench", root / "perfbench",
                     ignore=shutil.ignore_patterns(".out", "__pycache__"))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    name = "tiny-" + "-".join(archs)
-    nodes = [{"arch": TINY_MODELS[a]["name"], "source": "test",
-              "note": "CPU rehearsal size", "model": TINY_MODELS[a]}
-             for a in archs]
+    models = [a if isinstance(a, dict) else TINY_MODELS[a] for a in archs]
+    name = "tiny-" + "-".join(a if isinstance(a, str) else a["name"]
+                              for a in archs)
+    nodes = [{"arch": m["name"], "source": "test",
+              "note": "CPU rehearsal size", "model": m} for m in models]
     cfg = {"name": name, "deployment": "test", "knee_rps": knee,
            "reduced": [], "dtype": "float32", "nodes": nodes,
            "check": {"limits": limits or {

@@ -1,8 +1,9 @@
 """Seeded weights, made on the device in one jitted call per node.
 
-The layout is written out here from the configuration (the names and
-shapes the serving model reads), and every leaf is drawn from the seed
-by a rule of the benchmark's own: dense matrices N(0, 1/d_in), token
+The layout is written out from the configuration (the names and shapes
+the serving model reads): the model-level leaves here, each layer's by
+its kind's module in ``perfbench/arch/``.  Every leaf is drawn from the
+seed by a rule of the benchmark's own: dense matrices N(0, 1/d_in), token
 embeddings N(0, 0.02^2), norm scales 1, biases 0, and Mamba's fixed
 initial values (dt bias -4.6, A_log = log(1..state), D = 1).  The
 program is handed these arrays; the plain reference makes the same ones
@@ -16,10 +17,12 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from perfbench import arch
+
 Shape = Tuple[int, ...]
 
 
-def _norm(cfg) -> Dict[str, tuple]:
+def norm(cfg) -> Dict[str, tuple]:
     d = cfg.d_model
     if cfg.norm_type == "rmsnorm":
         return {"scale": ((d,), "ones")}
@@ -28,75 +31,15 @@ def _norm(cfg) -> Dict[str, tuple]:
     return {}
 
 
-def _dense(d_in: int, d_out: int) -> tuple:
+def dense(d_in: int, d_out: int) -> tuple:
     return ((d_in, d_out), ("normal", 1.0 / math.sqrt(d_in)))
-
-
-def _attention(cfg) -> dict:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    p = {"wq": _dense(d, cfg.num_heads * hd),
-         "wk": _dense(d, cfg.num_kv_heads * hd),
-         "wv": _dense(d, cfg.num_kv_heads * hd),
-         "wo": _dense(cfg.num_heads * hd, d)}
-    if cfg.qk_norm:
-        p["q_norm"] = ((hd,), "ones")
-        p["k_norm"] = ((hd,), "ones")
-    return p
-
-
-def _mamba(cfg) -> dict:
-    d = cfg.d_model
-    inner = cfg.ssm.expand * d
-    st, w = cfg.ssm.state_size, cfg.ssm.conv_width
-    r = max(1, math.ceil(d / 16))
-    return {
-        "in_proj": _dense(d, 2 * inner),
-        "conv_w": ((w, inner), ("normal", 1.0 / math.sqrt(w))),
-        "conv_b": ((inner,), "zeros"),
-        "x_proj": _dense(inner, r + 2 * st),
-        "dt_proj": _dense(r, inner),
-        "dt_bias": ((inner,), ("const", -4.6)),
-        "A_log": ((inner, st), "a_log", "float32"),
-        "D": ((inner,), "ones", "float32"),
-        "out_proj": _dense(inner, d),
-    }
-
-
-def _block(cfg, kind: str) -> dict:
-    d = cfg.d_model
-    p: dict = {"ln1": _norm(cfg)}
-    if kind in ("attn", "local"):
-        p["attn"] = _attention(cfg)
-    elif kind == "hymba":
-        p["attn"] = _attention(cfg)
-        p["mamba"] = _mamba(cfg)
-        p["bn_a"] = _norm(cfg)
-        p["bn_m"] = _norm(cfg)
-    elif kind == "mlstm":
-        H, hd = cfg.num_heads, cfg.resolved_head_dim
-        p["cell"] = {"wq": _dense(d, H * hd), "wk": _dense(d, H * hd),
-                     "wv": _dense(d, H * hd), "wi": _dense(d, H),
-                     "wf": _dense(d, H), "wog": _dense(d, H * hd),
-                     "out": _dense(H * hd, d)}
-    elif kind == "slstm":
-        p["cell"] = {"w": _dense(d, 4 * d),
-                     "r": ((4 * d,), ("normal", 0.1)),
-                     "out": _dense(d, d)}
-    else:
-        raise ValueError(f"layer kind {kind!r} has no weight layout here")
-    if kind not in ("mlstm", "slstm") and cfg.mlp_type != "none":
-        if cfg.moe is not None or cfg.mlp_type not in ("swiglu", "gelu_glu"):
-            raise ValueError(f"mlp {cfg.mlp_type!r}/moe has no layout here")
-        p["ln2"] = _norm(cfg)
-        p["mlp"] = {"wi": _dense(d, cfg.d_ff), "wg": _dense(d, cfg.d_ff),
-                    "wo": _dense(cfg.d_ff, d)}
-    return p
 
 
 def layout(cfg) -> dict:
     """Nested dict of leaf specs ``(shape, rule[, dtype])``; decoder
     blocks are stacked over pattern cycles (leading dim ``n_cycles``)
-    under slot names ``s<i>_<kind>``."""
+    under slot names ``s<i>_<kind>``, each laid out by its kind's module
+    in ``perfbench/arch/``."""
     n_cyc = cfg.num_layers // len(cfg.layer_pattern)
     if cfg.pos_embedding not in ("rope", "none") or cfg.is_encoder_decoder:
         raise ValueError("only rope / position-free decoders have a layout")
@@ -107,11 +50,14 @@ def layout(cfg) -> dict:
         return ((n_cyc,) + tree[0],) + tree[1:]
 
     lay = {"embed": ((cfg.vocab_size, cfg.d_model), ("normal", 0.02)),
-           "blocks": {f"s{i}_{k}": stack(_block(cfg, k))
+           "blocks": {f"s{i}_{k}": stack(arch.kind(k).block(cfg))
                       for i, k in enumerate(cfg.layer_pattern)},
-           "final_norm": _norm(cfg)}
+           "final_norm": norm(cfg)}
     if not cfg.tie_embeddings:
-        lay["lm_head"] = _dense(cfg.d_model, cfg.vocab_size)
+        lay["lm_head"] = dense(cfg.d_model, cfg.vocab_size)
+    extra = arch.model_part(cfg.layer_pattern, "extra_layout")
+    if extra is not None:
+        lay.update(extra(cfg))
     return lay
 
 
