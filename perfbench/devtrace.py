@@ -1,8 +1,8 @@
 """Reduction of a JAX profiler trace to device numbers.
 
 The trace is read once into plain events ``(plane, line, name, start_ns,
-dur_ns)``; everything else works on those, so the arithmetic is checked
-on a small recorded event list.
+dur_ns)``; everything else, here and in ``progtrace``, works on those,
+so the arithmetic is checked on a small recorded event list.
 
 * busy: the union of the intervals in which an operation ran on a
   device (lines named ``XLA Ops`` of ``/device:TPU:<n>`` planes),
@@ -15,7 +15,7 @@ on a small recorded event list.
   covers the gap's midpoint;
 * a cut device trace: the profiler keeps a bounded number of device
   events (about 6.2 million on a v5e, some 20 s of a busy window), and
-  ``load`` reads fewer still, so the device's events can stop while the
+  ``read`` takes fewer still, so the device's events can stop while the
   host still drives the engines.  ``device_cut`` finds that point;
   device numbers are then read over the part of the window the events
   cover, never over a tail that was not read.
@@ -28,9 +28,7 @@ blur the naming of gaps shorter than a few milliseconds.
 from __future__ import annotations
 
 import functools
-import glob
 import itertools
-import os
 import re
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -47,48 +45,52 @@ class Event:
     dur_ns: float
 
 
-# Device ops read per device: about ten seconds of a busy v5e, and
-# about half of what its profiler keeps before it stops recording.
+# Device events read per line of a device: about ten seconds of the ops
+# of a busy v5e, and about half of what its profiler keeps before it
+# stops recording.
 MAX_DEVICE_OPS = 3_000_000
+OPS = "XLA Ops"
+MODULES = "XLA Modules"
+# host annotations: the harness's own, and those the program's live
+# ``obs`` spans enter while tracing is on
+HOST_PREFIXES = ("bench.", "obs.")
 
 
-def load(trace_dir: str, max_device_ops: int = MAX_DEVICE_OPS
-         ) -> List[Event]:
-    """The events of the newest ``.xplane.pb`` under ``trace_dir`` that
-    the reduction reads: device ops, the first ``max_device_ops`` of
-    each device, and host annotations named ``bench.*``.  A busy window
-    holds millions of device ops, so nothing else is turned into Python
-    objects; past the limit, ``device_cut`` finds the end of what was
-    read as it finds the end of a cut trace."""
-    import jax
-    files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    if not files:
-        return []
-    pd = jax.profiler.ProfileData.from_file(files[-1])
+def read(planes, max_device_ops: int = MAX_DEVICE_OPS) -> List[Event]:
+    """The events of a profile that the reduction reads: of each TPU
+    plane, the first ``max_device_ops`` events of its ``XLA Ops`` line
+    (one per operation) and of its ``XLA Modules`` line (one per program
+    execution); of the host planes, the annotations named ``bench.*``
+    or ``obs.*``.  A busy window holds millions of device ops, so
+    nothing else is turned into Python objects; past the limit,
+    ``device_cut`` finds the end of what was read as it finds the end of
+    a cut trace."""
     out = []
-    for plane in pd.planes:
+    for plane in planes:
         pn = plane.name
-        device = pn.startswith("/device:")
-        for line in plane.lines:
-            ln = line.name
-            if device:
-                if pn.startswith("/device:TPU:") and ln == "XLA Ops":
+        if pn.startswith("/device:"):
+            if not pn.startswith("/device:TPU:"):
+                continue
+            for line in plane.lines:
+                ln = line.name
+                if ln == OPS or ln == MODULES:
                     out.extend([Event(pn, ln, e.name, float(e.start_ns),
                                       float(e.duration_ns))
                                 for e in itertools.islice(line.events,
                                                           max_device_ops)])
-                continue
+            continue
+        for line in plane.lines:
+            ln = line.name
             for e in line.events:
                 nm = e.name
-                if nm.startswith("bench."):
+                if nm.startswith(HOST_PREFIXES):
                     out.append(Event(pn, ln, nm, float(e.start_ns),
                                      float(e.duration_ns)))
     return out
 
 
 def is_device_op(e: Event) -> bool:
-    return e.plane.startswith("/device:TPU:") and e.line == "XLA Ops"
+    return e.plane.startswith("/device:TPU:") and e.line == OPS
 
 
 def union(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float,

@@ -474,6 +474,72 @@ class Probe:
         return out
 
 
+# The traced run profiles the end of its window only.  The profiler's
+# stop grows with the device ops it kept: a whole 51 s window fills its
+# buffer (about 6.2 million ops, the first 20-24 s of a busy v5e), and
+# its stop took 185 s written to a file and 88 s handed over in memory
+# (at host level 1, without HLO protos), while the reduction reads half
+# of those ops.  The last TRACED_S seconds hold about what
+# ``devtrace.MAX_DEVICE_OPS`` reads (3.0 million in 12.0 s).
+TRACED_S = 12.0
+# the front loop's own annotations, entered between two slots: no request
+# is in flight there, since ``run_slot`` returns once all its requests
+# are answered
+BETWEEN_SLOTS = ("bench.front_wait", "bench.run_slot")
+
+
+class TracedProbe(Probe):
+    """The probe of a traced run.  At the first point between two slots
+    that lies at most ``TRACED_S`` before the window closes (at once in
+    a shorter window) it starts the profiler and enters ``bench.traced``,
+    which stays open until the window has closed: the covered part.  It
+    starts nowhere else, so every request of the covered part, and every
+    ``obs`` annotation its spans enter, is whole in the trace."""
+
+    def __init__(self, w: World, t0: float, seconds: float):
+        super().__init__(w, t0, True)
+        self.trace_from = max(0.0, seconds - TRACED_S)
+        self.session = None
+        self.covered = None
+        self.traced_at: Optional[float] = None  # harness clock, at start
+        self.start_s: Optional[float] = None    # the front loop's stall
+        self.stop_s: Optional[float] = None
+
+    def _ann(self, name: str):
+        if self.session is None and name in BETWEEN_SLOTS \
+                and self.now() >= self.trace_from:
+            self._start()
+        return super()._ann(name)
+
+    def _start(self) -> None:
+        import jax
+        from jax._src.lib import _profiler
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        t = time.perf_counter()
+        self.session = _profiler.ProfilerSession(opts)
+        self.start_s = time.perf_counter() - t
+        self.covered = jax.profiler.TraceAnnotation("bench.traced")
+        self.covered.__enter__()
+        self.traced_at = self.now()
+        log(f"trace: the profiler started {self.traced_at:.3f} s into the "
+            f"window, in {self.start_s:.4f} s")
+
+    def stop(self):
+        """Close the covered part and stop the profiler: its profile, or
+        None where it never started."""
+        if self.session is None:
+            log("trace: the profiler never started")
+            return None
+        self.covered.__exit__(None, None, None)
+        t = time.perf_counter()
+        profile = self.session.stop_and_get_profile_data()
+        self.stop_s = time.perf_counter() - t
+        log(f"trace: stop_trace took {self.stop_s:.1f} s")
+        return profile
+
+
 # ----------------------------------------------------------------- window
 
 
@@ -567,21 +633,14 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     warm["warm_s"] = round(time.perf_counter() - t_w, 3)
     if fault is not None:
         check.plant_fault(w, fault)
-    trace_dir = root / "perfbench" / ".out" / f"trace-{workload}"
     rec = None
     if trace:
-        import shutil
         from repro import obs
         from repro.obs.recorder import FlightRecorder
-        shutil.rmtree(trace_dir, ignore_errors=True)
         rec = obs.enable(FlightRecorder(1 << 20))
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     t0 = time.perf_counter()
     setup_s = t0 - t_start
-    probe = Probe(w, t0, trace)
+    probe = TracedProbe(w, t0, seconds) if trace else Probe(w, t0, trace)
     base = probe.counters()
     probe.compiles = 0
     updates0 = w.runtime.identifier.updates_done
@@ -602,9 +661,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     spans = []
     if trace:
         from repro import obs
-        t_stop = time.perf_counter()
-        jax.profiler.stop_trace()
-        log(f"trace: stop_trace took {time.perf_counter() - t_stop:.1f} s")
+        profile = probe.stop()
         obs.disable()
         spans = [e for e in rec.events() if e.get("kind") == "span"]
     recs = [probe.recs[k] for k in sorted(probe.recs)]
@@ -629,8 +686,10 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         info = dict(win=win, delta=delta, spans=spans, probe=probe,
                     recs=recs, cell=cell, model_dicts=[n["model"] for n in cell.config["nodes"]],
-                    device=device, same_sessions=probe.same_sessions())
-        per_layer, dev_extra, breakdown = read_trace(info, trace_dir)
+                    device=device, same_sessions=probe.same_sessions(),
+                    traced_at_s=probe.traced_at)
+        per_layer, dev_extra, breakdown = read_trace(info, profile)
+        del profile
         device.update(dev_extra)
     # the program's state goes before the reference runs
     sample = check.collect(w, recs)
@@ -646,6 +705,13 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
                            control=control)
     log(f"check: {time.perf_counter() - t_check:.1f} s for "
         f"{sum(len(v) for v in sample.values())} sampled requests")
+    if trace:
+        t_end = time.perf_counter()
+        log(f"trace: phases (s): set-up {setup_s:.1f}, window "
+            f"{win['span_s']:.1f}, start stall {probe.start_s or 0.0:.4f}, "
+            f"stop {probe.stop_s or 0.0:.1f}, read {info['read_s']:.1f}, "
+            f"reduce {info['reduce_s']:.1f}, check {t_end - t_check:.1f}; "
+            f"whole run {t_end - t_start:.1f} of the 360 allowed")
     check.print_checked(verdict["checked"])
     out = assemble(cell, recs, win, setup_s, device, verdict, per_layer,
                    trace, breakdown)
@@ -655,30 +721,37 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     return out
 
 
-def read_trace(info: dict, trace_dir: Path):
+def read_trace(info: dict, profile):
     """Per-layer metrics, the device's busy and window seconds, and the
-    breakdown, from the profiler trace and the run's own records."""
+    breakdown, from the profile of the covered part and the run's own
+    records."""
     # millions of device events become Python objects: the cyclic
     # collector, scanning the whole cluster's objects again and again
     # while they are made, would take most of the reading time
     gc.disable()
     try:
-        return _read_trace(info, trace_dir)
+        return _read_trace(info, profile)
     finally:
         gc.enable()
 
 
-def _read_trace(info: dict, trace_dir: Path):
+# a start of the profiler that held the front loop longer than this is
+# named among the idle gaps (it lies before the covered part)
+START_STALL_S = 0.1
+
+
+def _read_trace(info: dict, profile):
     from perfbench import devtrace
     cell = info["cell"]
     t = time.perf_counter()
     try:
-        events = devtrace.load(str(trace_dir))
+        events = devtrace.read(profile.planes) if profile is not None \
+            else []
     except Exception as e:          # an unreadable trace reads as nothing
         log(f"trace: could not read ({e!r})")
         events = []
     t_load = time.perf_counter() - t
-    win = devtrace.annotation_window(events, "bench.window")
+    win = devtrace.annotation_window(events, "bench.traced")
     info["events"] = events
     info["trace_window"] = win
     dev_extra, breakdown = {}, None
@@ -689,13 +762,20 @@ def _read_trace(info: dict, trace_dir: Path):
         n_ops = sum(1 for e in events if devtrace.is_device_op(e)
                     and win[0] <= e.start_ns < win[1])
         msg = f"trace: {n_ops} device op events in the " \
-            f"{(win[1] - win[0]) / 1e9:.3f} s window"
+            f"{(win[1] - win[0]) / 1e9:.3f} s covered part of the window"
         if cut is not None:
             msg += f"; the device trace stops {(cut - win[0]) / 1e9:.3f} " \
                 "s in while the engines still ran: device numbers cover " \
                 "that part"
         log(msg)
         info["device_window"] = dwin
+        since = info["traced_at_s"]
+        until = since + (dwin[1] - win[0]) / 1e9
+        nodes = [r.node for r in info["recs"]
+                 if r.tokens and since <= r.done <= until]
+        by_node = [nodes.count(n) for n in range(len(info["model_dicts"]))]
+        log(f"trace: {len(nodes)} requests answered in the device window, "
+            f"by node {by_node}")
         busy, merged = devtrace.device_busy(events, *dwin)
         info["busy_s"] = busy
         dev_extra = {"busy_s": busy, "window_s": (dwin[1] - dwin[0]) / 1e9}
@@ -704,8 +784,13 @@ def _read_trace(info: dict, trace_dir: Path):
                                   where=True) if first else []
         log("trace: longest idle gaps (annotation, s, starting s in) "
             f"{[[g[0], round(g[1], 4), round(g[2], 3)] for g in gaps[:4]]}")
+        gaps = [g[:2] for g in gaps]
+        stall = info["probe"].start_s
+        if stall > START_STALL_S:
+            gaps = sorted(gaps + [["start_trace", stall]],
+                          key=lambda g: -g[1])[:10]
         breakdown = {"device_ops": devtrace.top_ops(events, *dwin),
-                     "idle_gaps": [g[:2] for g in gaps]}
+                     "idle_gaps": gaps}
     out = {}
     for name, reader in cell.readers.items():
         try:
@@ -715,8 +800,8 @@ def _read_trace(info: dict, trace_dir: Path):
             v = None
         if v is not None and math.isfinite(v):
             out[name] = float(v)
-    log(f"trace: read in {t_load:.1f} s, reduced in "
-        f"{time.perf_counter() - t - t_load:.1f} s")
+    info["read_s"] = t_load
+    info["reduce_s"] = time.perf_counter() - t - t_load
     return out, dev_extra, breakdown
 
 

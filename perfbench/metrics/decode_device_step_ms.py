@@ -4,14 +4,15 @@ the program's table (``serving/engine.py::PROGRAM_LAYERS``) puts in
 (attribute ``steps``) that start in the device window on the shared
 clock, in ms a step."""
 from perfbench import progtrace
+from perfbench.metrics import _spans
 
 
 def read(run):
     win = run.get("device_window")
-    segs = progtrace.spans_in_window(run, "decode_segment")
+    segs = _spans.spans_in_window(run, "decode_segment")
     if win is None or not segs:
         return None
     steps = sum((e.get("attrs") or {}).get("steps", 0)
-                for e in progtrace.once(segs))
+                for e in _spans.once(segs))
     t = progtrace.device_seconds(progtrace.events(run), "decode", *win)
     return 1e3 * t / steps if t and steps else None

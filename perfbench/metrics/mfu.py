@@ -1,15 +1,17 @@
 """Device, whole step: model FLOPs of the tokens actually prefilled
 (forked prefixes not again) and generated, per node from the
-configuration's shapes (``perfbench/peaks.py``), over the traced window
-times the chip's peak, in %."""
+configuration's shapes (``perfbench/peaks.py``), over the measured
+window times the chip's peak, in %.  Every request of the window counts,
+over the window's span on the harness's clock (the traced run's
+profiler covers only the end of the window); a run whose trace could
+not be read reads as nothing, as the other device numbers do."""
 from perfbench import peaks
 
 
 def read(run):
-    win = run.get("trace_window")
-    if win is None:
+    if run.get("device_window") is None:
         return None
-    span = (win[1] - win[0]) / 1e9
+    span = run["win"]["span_s"]
     models = run["model_dicts"]
     flops = 0.0
     for r in run["recs"]:

@@ -3,11 +3,12 @@ programs the program's table (``serving/engine.py::PROGRAM_LAYERS``)
 puts in ``prefill``, over the per-request ``prefill`` spans that start
 in the device window on the shared clock, in ms a request."""
 from perfbench import progtrace
+from perfbench.metrics import _spans
 
 
 def read(run):
     win = run.get("device_window")
-    pre = progtrace.spans_in_window(run, "prefill")
+    pre = _spans.spans_in_window(run, "prefill")
     if win is None or pre is None:
         return None
     n = sum(1 for e in pre if e.get("trace", "-") != "-")

@@ -96,7 +96,8 @@ def test_unknown_kind_is_an_error_naming_it(where):
         "peaks.paged_kv_bytes": lambda: peaks.paged_kv_bytes(UNKNOWN, 64, 8),
         # never a share of the peak over a count of 0
         "mfu": lambda: mfu.read({
-            "trace_window": (0, 10**9), "model_dicts": [UNKNOWN],
+            "device_window": (0, 10**9), "win": {"span_s": 1.0},
+            "model_dicts": [UNKNOWN],
             "recs": [Record(0, 0.0, node=0, prompt=[5] * 20,
                             tokens=[6] * 4)],
             "probe": type("P", (), {"prefix_prefills": []})(),

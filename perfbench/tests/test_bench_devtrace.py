@@ -117,8 +117,8 @@ def test_idle_end_of_window_is_not_a_cut():
     assert devtrace.device_cut(ev, 0, 10e9) is None
 
 
-def test_load_reads_at_most_the_limit_of_device_ops(tmp_path):
-    """A trace file with more device ops than the limit: the first ones
+def test_load_reads_at_most_the_limit_of_device_ops():
+    """A profile with more device ops than the limit: the first ones
     are read, host annotations all, and the end of what was read is
     found as a cut."""
     from jax._src.lib import _profile_data
@@ -137,8 +137,8 @@ def test_load_reads_at_most_the_limit_of_device_ops(tmp_path):
         'value { id: 2 name: "bench.node0.engine" } } event_metadata { '
         'key: 3 value { id: 3 name: "runtime internals" } } }')
     xs = _profile_data.ProfileData.text_proto_to_serialized_xspace(text)
-    (tmp_path / "t.xplane.pb").write_bytes(xs)
-    ev = devtrace.load(str(tmp_path), max_device_ops=20)
+    profile = _profile_data.ProfileData.from_serialized_xspace(xs)
+    ev = devtrace.read(profile.planes, max_device_ops=20)
     assert sum(devtrace.is_device_op(e) for e in ev) == 20
     assert {e.name for e in ev if e.plane == "/host:CPU"} == {
         "bench.window", "bench.node0.engine"}

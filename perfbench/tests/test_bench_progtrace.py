@@ -11,6 +11,7 @@ import pytest
 
 from perfbench import devtrace, progtrace, spec
 from perfbench.devtrace import Event
+from perfbench.metrics import _spans
 
 DATA = Path(__file__).resolve().parent / "data"
 NEW = ("host_bound_idle_share", "engine_idle_share", "decode_device_step_ms",
@@ -30,34 +31,43 @@ def _line(name, *events):
 
 
 def test_select_keeps_modules_and_obs_annotations():
+    """``devtrace.read`` keeps, besides the ops and ``bench.*``, the
+    ``XLA Modules`` events of the TPU planes and the host ``obs.*``
+    annotations of every host line; the cap applies per device line."""
     planes = [
         SimpleNamespace(name="/device:TPU:0", lines=[
             _line("XLA Ops", ("%fusion.1 = f32[4]{0} fusion()", 0, 5)),
             _line("XLA Modules", ("jit__decode_cont_impl(1)", 0, 10),
                   ("jit__paged_refill_impl(2)", 20, 5),
-                  ("jit__decode_cont_impl(1)", 30, 10))]),
+                  ("jit__decode_cont_impl(1)", 30, 10)),
+            _line("Steps", ("1", 0, 40))]),
+        SimpleNamespace(name="/device:CPU:0", lines=[
+            _line("XLA Modules", ("jit_f(3)", 0, 1))]),
         SimpleNamespace(name="/host:CPU", lines=[
             _line("python", ("obs.request", 0, 50), ("bench.window", 0, 99),
                   ("PjitFunction(f)", 1, 1), ("obs.generate", 5, 20)),
             _line("other", ("obs.request", 3, 4))]),
     ]
-    ev = progtrace.select(planes)
-    assert [(e.line, e.name) for e in ev] == [
+    ev = devtrace.read(planes)
+    assert [(e.line, e.name) for e in progtrace.events({"events": ev})] == [
         ("XLA Modules", "jit__decode_cont_impl(1)"),
         ("XLA Modules", "jit__paged_refill_impl(2)"),
         ("XLA Modules", "jit__decode_cont_impl(1)"),
         ("python", "obs.request"), ("python", "obs.generate"),
         ("other", "obs.request")]
-    # the cap applies per line, and host lines can be named
-    ev = progtrace.select(planes, {("/host:CPU", "python")}, max_per_line=2)
+    assert [e.name for e in ev if devtrace.is_device_op(e)] == [
+        "%fusion.1 = f32[4]{0} fusion()"]
+    assert [e.name for e in ev if e.name.startswith("bench.")] == [
+        "bench.window"]
+    ev = devtrace.read(planes, max_device_ops=2)
     assert sum(e.line == "XLA Modules" for e in ev) == 2
-    assert [e.line for e in ev if e.name.startswith("obs.")] == ["python"] * 2
 
 
 def test_harness_reductions_unchanged_beside_program_events():
     """The recorded v5e probe holds six ``XLA Modules`` events; with those
-    and ``obs.*`` annotations beside the events ``devtrace.load`` keeps,
-    every reduction of ``devtrace`` reads the same numbers."""
+    and ``obs.*`` annotations beside the device ops and ``bench.*``
+    annotations, every reduction of ``devtrace`` reads the same numbers
+    as on those alone."""
     rows = json.loads((DATA / "v5e_probe_events.json").read_text())
     every = [Event(*r) for r in rows]
     kept = [e for e in every if e.line != "XLA Modules"]
@@ -143,18 +153,18 @@ def _readers():
 
 def test_shared_clock_recovers_the_offset():
     run = _run()
-    got = progtrace.offset_from(run["spans"], [
+    got = _spans.offset_from(run["spans"], [
         e for e in run["events"] if e.name == "obs.request"])
     assert got["pairs"] == 2 * SLOTS
     assert got["offset_ns"] == pytest.approx(OFF, abs=1e3)
     assert got["spread_ns"] <= 2e3
-    assert progtrace.clock(run) == pytest.approx(OFF, abs=1e3)
+    assert _spans.clock(run) == pytest.approx(OFF, abs=1e3)
 
 
 def test_clock_refused_when_residuals_spread_or_pairs_are_few():
-    assert progtrace.clock(_run(jitter_ns=200e3)) is None
-    assert progtrace.clock(_run(slots=9)) is None       # 18 pairs
-    assert progtrace.clock(_run(slots=10)) is not None  # 20 pairs
+    assert _spans.clock(_run(jitter_ns=200e3)) is None
+    assert _spans.clock(_run(slots=9)) is None       # 18 pairs
+    assert _spans.clock(_run(slots=10)) is not None  # 20 pairs
 
 
 def test_idle_split_by_innermost_annotation():
