@@ -48,8 +48,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, first, last, *,
     table.  [B,H,hd] x pool [P,bs,KV,hd]^2 x tables [B,nb] -> [B,H,hd].
 
     ``use_pallas=None`` resolves by backend: the TPU path runs the
-    PrefetchScalarGridSpec kernel; elsewhere the jnp oracle serves (the
-    interpreter would re-walk the grid per decode step)."""
+    Pallas kernel, which reads only each row's live blocks; elsewhere
+    the jnp oracle serves (the interpreter would replay the kernel's
+    copies op by op every decode step).  A row with ``last`` below its
+    ``first`` reads zeros on both paths."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:

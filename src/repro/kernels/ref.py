@@ -52,7 +52,9 @@ def paged_attention_ref(
 ) -> jax.Array:
     """Oracle for the paged decode kernel: gather each row's blocks out
     of the pool, mask by position validity ``first <= pos <= last`` (and
-    block allocation), f32 softmax; GQA broadcast.  -> [B, H, hd]."""
+    block allocation), f32 softmax; GQA broadcast.  A row with no valid
+    slot (``last = -1`` marks a finished row) reads zeros.
+    -> [B, H, hd]."""
     B, H, hd = q.shape
     P, bs, KV, _ = k_pool.shape
     nb = block_tables.shape[1]
@@ -70,6 +72,7 @@ def paged_attention_ref(
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskh->bkgh", p, v)
+    o = jnp.where(mask.any(axis=1)[:, None, None, None], o, 0.0)
     return o.reshape(B, H, hd).astype(q.dtype)
 
 

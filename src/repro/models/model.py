@@ -262,14 +262,16 @@ class Model:
         return a, new_kv
 
     def _paged_decode_attn(self, q, kv_stack, cycle, start, tables, nb_cap,
-                           pos_shift, softcap=None):
+                           pos_shift, active=None, softcap=None):
         """Paged decode read for a pooled "attn" slot: write the token
         into its block (frozen rows scatter nowhere), then attend
         through the first ``nb_cap`` block-table columns via the paged
         attention kernel/oracle — O(live blocks), not O(max_len).
         ``start`` [B] is per-row; valid slots are first <= pos <= start
-        (start is the just-written position).  Returns attn [B,1,H,hd];
-        the write happens in the caller (needs k/v)."""
+        (start is the just-written position).  A row with ``active``
+        False reads nothing (its last position is -1) and gets zeros.
+        Returns attn [B,1,H,hd]; the write happens in the caller (needs
+        k/v)."""
         # view the cycle-stacked pool as one [nc*P, bs, KV, hd] pool and
         # offset the tables into the live cycle's stripe — extracting the
         # cycle slice would copy the whole pool every decode step
@@ -278,9 +280,10 @@ class Model:
         v_pool = kv_stack["v"].reshape((nc * P,) + kv_stack["v"].shape[2:])
         tbl = tables[:, :nb_cap]
         tbl = jnp.where(tbl >= 0, tbl + cycle * P, -1)
+        last = start if active is None else jnp.where(active, start, -1)
         a = kernel_ops.paged_decode_attention(
             q[:, 0], k_pool, v_pool, tbl,
-            pos_shift, start, softcap=softcap)
+            pos_shift, last, softcap=softcap)
         return a[:, None]
 
     def _attn_sublayer(self, p, x, kind, qpos, kpos, angles, kv_stack, mode,
@@ -302,7 +305,7 @@ class Model:
                     kv_stack, k, v, start, ctx["tables"], cycle, active)
                 a = self._paged_decode_attn(
                     q, new_kv, cycle, start, ctx["tables"], ctx["nb_cap"],
-                    pos_shift, softcap=cfg.attn_logit_softcap)
+                    pos_shift, active, softcap=cfg.attn_logit_softcap)
             else:
                 new_kv = cache_lib.rolling_write_token(
                     kv_stack, k, v, start, cycle, active)

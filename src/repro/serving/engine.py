@@ -843,14 +843,16 @@ class ContinuousSession:
         # slot -> request trace id (set by the scheduler at admission);
         # decode-segment spans and prefix-cache events attribute to it
         self.traces: Dict[int, Optional[str]] = {}
-        # paged mode: host-side block bookkeeping.  ``lengths`` mirrors
-        # the per-row cache["length"]; ``_tables`` mirrors the rows'
-        # block tables so freed rows can return their blocks.
+        # paged mode: host-side block bookkeeping.  ``lengths`` and
+        # ``_first`` mirror the per-row cache["length"] and ["first"];
+        # ``_tables`` mirrors the rows' block tables so freed rows can
+        # return their blocks.
         self.paged = engine.paged
         self.prefix_cache = None
         if engine.paged:
             self.allocator = cache_lib.BlockAllocator(engine.num_blocks)
             self.lengths = np.zeros(self.B, np.int64)
+            self._first = np.zeros(self.B, np.int64)
             self._tables = np.full((self.B, engine.nb_total), -1, np.int32)
             if prefix_cache is not None:
                 from repro.serving.prefix_cache import PrefixCache
@@ -1061,6 +1063,7 @@ class ContinuousSession:
             self.cache = cache
             self._tables = tables
             self.lengths = np.full(self.B, frame_len, np.int64)
+            self._first = first.astype(np.int64)
         else:
             cache = self.eng._fresh_cache(jnp.asarray(first),
                                           jnp.zeros((), jnp.int32))
@@ -1142,6 +1145,7 @@ class ContinuousSession:
             jnp.int32(l_end), jnp.int32(first0), gp=self.gen)
         self._tables[slot] = table_row
         self.lengths[slot] = l_end
+        self._first[slot] = first0
 
     def _refill_plain(self, slot: int, prompt: Sequence[int],
                       budget: int) -> None:
@@ -1227,6 +1231,13 @@ class ContinuousSession:
         B = self.B
         live = ~self.done
         rem = self._budget[live] - self.idx[live]
+        if self.paged:
+            cap = None
+            nbc = self.eng._cont_nb_cap(
+                int((self.lengths[live] + rem).max()) + 2)
+        else:
+            cap = self.eng._cont_kv_cap(self.length + int(rem.max()) + 2)
+            nbc = None
         # batched multi-trace span: one wall-clock interval, one event
         # per live request.  Guarded on tr.enabled so the disabled path
         # makes zero clock reads (NULL_SPAN; see tests/test_obs.py)
@@ -1234,21 +1245,23 @@ class ContinuousSession:
         sp = obs_trace.NULL_SPAN
         if tr.enabled:
             tstep0 = self.tstep
+            attrs = {}
+            if self.paged:
+                # blocks the paged kernel reads at entry (a row's
+                # columns first // bs .. length // bs) against the
+                # B x nb_cap a kernel walking every column reads
+                bs = self.eng.block_size
+                attrs = {"kv_blocks_live": int(
+                    (self.lengths[live] // bs - self._first[live] // bs
+                     + 1).sum()), "kv_blocks_grid": B * nbc}
             tif = int(self.lengths[live].sum()) if self.paged \
                 else int(live.sum()) * self.length
             sp = tr.span("decode_segment",
                          traces=[self.traces.get(int(i))
                                  for i in np.nonzero(live)[0]],
                          rows=int(live.sum()), tokens_in_flight=tif,
-                         drain=bool(drain))
+                         drain=bool(drain), **attrs)
         with sp:
-            if self.paged:
-                cap = None
-                nbc = self.eng._cont_nb_cap(
-                    int((self.lengths[live] + rem).max()) + 2)
-            else:
-                cap = self.eng._cont_kv_cap(self.length + int(rem.max()) + 2)
-                nbc = None
             (self.tok, self._done_d, self._rem_d, self._idx_d, self.out,
              self.cache, summary) = self.eng._decode_cont(
                 self.eng.params, self.tok, self.cache, self._seg_key,

@@ -333,3 +333,29 @@ def test_traced_slot_names_each_node_stage(obs_cluster, tmp_path):
     # the dump with the new spans still passes the CI gate
     path = rec.export_jsonl(str(tmp_path / "stages.jsonl"))
     assert trace_report.main([path, "--check"]) == 0
+
+
+def test_paged_decode_segment_counts_kv_blocks(obs_cluster):
+    """A paged decode segment records, at its entry, the block-table
+    columns the paged kernel reads (each live row's first // bs to
+    length // bs) and the B x nb_cap columns of the whole table."""
+    nodes, _, _ = obs_cluster
+    eng = nodes[0].engine
+    prompts, budgets = [[5, 6, 7], [8, 9]], [3, 2]
+    rec = obs.enable()
+    try:
+        sess = eng.continuous_session(
+            GenerationParams(max_new_tokens=3), prefix_cache=2)
+        sess.begin_frame(prompts, budgets)
+        L, bs = sess.length, eng.block_size
+        sess.run_segment(drain=True)
+        sess.release()
+    finally:
+        obs.disable()
+    segs = [e for e in rec.events() if e["kind"] == "span"
+            and e["name"] == "decode_segment"]
+    live = sum(L // bs - (L - len(p)) // bs + 1 for p in prompts)
+    grid = eng.batch_size * eng._cont_nb_cap(L + max(budgets) + 2)
+    assert segs and all(e["attrs"]["kv_blocks_live"] == live
+                        and e["attrs"]["kv_blocks_grid"] == grid
+                        for e in segs)

@@ -6,6 +6,7 @@ agreement with a *solo* ``generate_reference`` run per prompt (batched
 references left-pad recurrent rows differently).  The paged path must
 additionally leave the block pool leak-free after ``release()``.
 """
+import functools
 import warnings
 
 import jax
@@ -311,29 +312,97 @@ def test_truncation_keeps_prefix_hash_stable(key):
 # ----------------------------------------------------------------- kernel
 
 
-@pytest.mark.parametrize("softcap", [None, 30.0])
-def test_paged_attention_kernel_matches_ref(softcap):
-    """Pallas paged decode kernel (interpret mode) vs the jnp oracle:
-    GQA broadcast, -1 (unallocated) table entries, per-row first/last
-    windows."""
-    rng = np.random.default_rng(0)
-    B, H, KV, hd, bs, nb, P = 3, 4, 2, 16, 8, 4, 10
+def _paged_case(B, H, KV, hd, bs, nb, alloc, first, last, seed=0):
+    """Random q and pool, and per-row tables holding ``alloc[b]``
+    distinct pool blocks (the rest -1)."""
+    rng = np.random.default_rng(seed)
+    P = sum(alloc) + 3
     q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
     k_pool = jnp.asarray(rng.standard_normal((P, bs, KV, hd)), jnp.float32)
     v_pool = jnp.asarray(rng.standard_normal((P, bs, KV, hd)), jnp.float32)
-    tables = jnp.asarray([[0, 1, 2, -1],
-                          [3, 4, -1, -1],
-                          [5, 6, 7, 8]], jnp.int32)
-    first = jnp.asarray([2, 0, 5], jnp.int32)
-    last = jnp.asarray([20, 9, 30], jnp.int32)
-    want = ref.paged_attention_ref(q, k_pool, v_pool, tables, first, last,
-                                   softcap=softcap)
-    from repro.kernels.paged_attention import paged_decode_attention_pallas
-    got = paged_decode_attention_pallas(q, k_pool, v_pool, tables, first,
-                                        last, softcap=softcap,
-                                        interpret=True)
+    ids = iter(rng.permutation(P).tolist())
+    tables = np.full((B, nb), -1, np.int32)
+    for b, n in enumerate(alloc):
+        tables[b, :n] = [next(ids) for _ in range(n)]
+    return (q, k_pool, v_pool, jnp.asarray(tables),
+            jnp.asarray(first, jnp.int32), jnp.asarray(last, jnp.int32))
+
+
+# (B, H, KV, hd, bs, nb), blocks allocated per row, first, last, softcap,
+# blocks per chunk (None: the kernel's own).  hd 128 takes the path that
+# copies blocks itself, narrower heads the grid's pipeline.
+PAGED_CASES = {
+    # GQA broadcast, -1 (unallocated) table entries, per-row windows
+    "gqa": ((3, 4, 2, 16, 8, 4), [3, 2, 4], [2, 0, 5], [20, 9, 30],
+            None, None),
+    "gqa-softcap": ((3, 4, 2, 16, 8, 4), [3, 2, 4], [2, 0, 5],
+                    [20, 9, 30], 30.0, None),
+    # olmo-1b's heads and block: 8 blocks a chunk; row 0 spans three
+    # chunks and ends mid-chunk, nb = 20 is not a multiple of 8
+    "mha-16x128": ((2, 16, 16, 128, 16, 20), [20, 9], [0, 3], [300, 130],
+                   None, None),
+    "gqa4-hd64": ((2, 8, 2, 64, 16, 6), [6, 4], [0, 7], [90, 60],
+                  None, 2),
+    "gqa4-hd128-softcap": ((2, 8, 2, 128, 8, 7), [7, 5], [3, 0],
+                           [50, 33], 30.0, 3),
+    "mha-softcap": ((2, 2, 2, 128, 8, 5), [5, 3], [1, 0], [38, 20],
+                    30.0, None),
+    # spans of 1..4 chunks of three blocks, the last cut mid-chunk
+    "multi-chunk": ((3, 2, 2, 128, 8, 11), [11, 7, 4], [0, 9, 2],
+                    [87, 54, 30], None, 3),
+    "nb-not-multiple": ((2, 2, 2, 128, 8, 7), [7, 7], [0, 0], [55, 17],
+                        None, 2),
+    "first-past-blocks": ((2, 2, 2, 128, 8, 9), [9, 9], [33, 20],
+                          [71, 25], None, 2),
+    # row 1 finished (last = -1): zeros, and the other rows as if it were
+    # live
+    "inactive-row": ((3, 2, 2, 128, 8, 6), [6, 5, 6], [0, 2, 5],
+                     [47, -1, 40], None, 2),
+    "inactive-row-narrow": ((3, 4, 4, 32, 8, 6), [6, 5, 6], [0, 2, 5],
+                            [47, -1, 40], None, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES), ids=list(PAGED_CASES))
+def test_paged_attention_kernel_matches_ref(case, monkeypatch):
+    """Pallas paged decode kernel vs the jnp oracle, in the TPU
+    interpreter (memory it never wrote reads NaN, a copy lands at its
+    wait); a row with nothing to attend reads zeros in both, and leaves
+    the other rows as they are."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import paged_attention as pa
+    shape, alloc, first, last, softcap, chunk = PAGED_CASES[case]
+    if chunk is not None:
+        _, _, KV, hd, bs, _ = shape
+        monkeypatch.setattr(pa, "CHUNK_BYTES", chunk * pa._block_vmem_bytes(
+            bs, KV, hd, jnp.float32))
+    args = _paged_case(*shape, alloc, first, last)
+    want = ref.paged_attention_ref(*args, softcap=softcap)
+    interpret = pltpu.InterpretParams(uninitialized_memory="nan")
+    got = pa.paged_decode_attention_pallas(*args, softcap=softcap,
+                                           interpret=interpret)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    dead = np.asarray(last) < np.asarray(first)
+    assert (np.asarray(got)[dead] == 0).all()
+    if dead.any():
+        live_last = np.where(dead, 40, last)
+        args2 = args[:5] + (jnp.asarray(live_last, jnp.int32),)
+        other = pa.paged_decode_attention_pallas(*args2, softcap=softcap,
+                                                 interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(other)[~dead],
+                                      np.asarray(got)[~dead])
+
+
+def test_paged_attention_chunk_follows_block_bytes():
+    """A chunk holds about CHUNK_BYTES of K as laid out in VMEM, never
+    more columns than the table has."""
+    from repro.kernels.paged_attention import blocks_per_chunk
+    assert blocks_per_chunk(16, 16, 128, jnp.float32, 72) == 8   # olmo
+    assert blocks_per_chunk(16, 16, 128, jnp.bfloat16, 72) == 16
+    assert blocks_per_chunk(16, 5, 64, jnp.float32, 72) == 16    # padded
+    assert blocks_per_chunk(16, 16, 128, jnp.float32, 3) == 3
 
 
 def test_paged_attention_all_blocks_unallocated_row():
@@ -355,6 +424,39 @@ def test_paged_attention_all_blocks_unallocated_row():
     assert np.isfinite(np.asarray(out_k)).all()
     np.testing.assert_allclose(np.asarray(out_k[0]), np.asarray(out[0]),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("arch,heads", [
+    pytest.param("olmo-1b", dict(head_dim=128), id="mha-lane-dense"),
+    pytest.param("llama3-8b", dict(num_kv_heads=2), id="gqa-narrow"),
+])
+def test_paged_segment_kernel_matches_oracle(arch, heads, key, monkeypatch):
+    """One drain segment in which a row finishes mid-segment and then
+    reads nothing: with the Pallas kernel forced on (interpret mode)
+    every row's tokens equal the jnp oracle path's, for heads the kernel
+    copies itself (hd 128) and heads the grid's pipeline copies."""
+    import dataclasses
+    prompts = [[5, 6, 7, 2, 3, 4, 1], [9, 3, 1, 5, 2, 6, 7, 4, 2, 8, 1],
+               [4, 4, 1, 3]]
+    budgets = [2, 7, 5]
+    cfg = dataclasses.replace(get_smoke_config(arch), **heads)
+    params = Model(cfg).init_params(key, max_seq=96)
+
+    def segment():
+        eng = ServeEngine(cfg, params, max_len=96, batch_size=4,
+                          prefill_chunk=8, paged=True, block_size=16)
+        sess = eng.continuous_session(GenerationParams(max_new_tokens=7),
+                                      key=jax.random.PRNGKey(7))
+        sess.begin_frame(prompts, budgets)
+        outs = dict(sess.run_segment(drain=True))
+        assert sorted(outs) == [0, 1, 2] and sess.segments == 1
+        return outs
+
+    want = segment()
+    assert [len(want[i]) for i in range(3)] == budgets
+    monkeypatch.setattr(ops, "paged_decode_attention", functools.partial(
+        ops.paged_decode_attention, use_pallas=True))
+    assert segment() == want
 
 
 def test_program_layer_table_names_every_jitted_program(key):
